@@ -3,8 +3,8 @@
 Every fresh process pays XLA trace+compile for its first jitted train step —
 the dominant startup cost for real configs.  This benchmark spawns fresh
 interpreters and measures that first-step wall time twice: COLD (persistent
-cache disabled via ``REPRO_COMPILECACHE=off``) and WARM (cache at
-``results/compilecache/`` populated by an unmeasured priming child), so
+cache disabled via ``REPRO_COMPILECACHE=off``) and WARM (the persistent cache
+populated by an unmeasured priming child), so
 "the cache makes restarts faster" is a ``stats.compare`` verdict over real
 process boundaries, not a same-process artifact.
 
@@ -13,6 +13,10 @@ candidate flag configuration is measured through ``child_env`` re-exec, the
 winner is promoted into the ConfigStore under this host's hardware
 fingerprint, and the promoted entry is resolved back — tuned XLA flags
 survive the process the same way tuned block sizes do.
+
+This parent never starts a JAX backend: a chip belongs to one process, and
+every child needs it.  The children report the cache directory and the
+hardware/software fingerprints the parent keys its records by.
 
     PYTHONPATH=src python benchmarks/compile_cold_warm.py [--quick]
 """
@@ -28,17 +32,18 @@ from typing import Any, Dict, List, Optional
 from repro.core import configstore, stats
 from repro.core.compilecache import (COMPONENT, ENV_CACHE_DIR, ENV_DISABLE,
                                      XLA_RUNTIME_SPACE, child_env,
-                                     persistent_cache_dir,
-                                     promote_xla_settings,
-                                     resolve_xla_settings)
+                                     promote_xla_settings)
 
 # Fresh-interpreter workload: the reduced olmo-1b train step (same recipe as
 # the tier-1 loss-decrease test).  The child reports its first jitted step's
-# wall time — trace + compile + first execute — plus the registry counters.
+# wall time — trace + compile + first execute — plus the registry counters,
+# the active cache directory and its fingerprints.
 _CHILD = """
 import json, time
 import jax
 from repro.configs import get_config
+from repro.core.compilecache import enable_persistent_cache
+from repro.core.configstore import hardware_fingerprint, sw_fingerprint
 from repro.core.telemetry import compile_cache_counters
 from repro.data.pipeline import PackedBatcher, SyntheticCorpus
 from repro.runtime.steps import init_train_state, jit_train_step
@@ -52,8 +57,11 @@ step = jit_train_step(cfg)
 t0 = time.perf_counter()
 state, metrics = step(state, batch, 1.0)
 jax.block_until_ready(metrics)
-print(json.dumps({"first_step_s": time.perf_counter() - t0,
-                  "counters": compile_cache_counters()}))
+dt = time.perf_counter() - t0
+cache_dir = enable_persistent_cache()
+print(json.dumps({"first_step_s": dt, "counters": compile_cache_counters(),
+                  "cache_dir": str(cache_dir) if cache_dir else None,
+                  "hardware": hardware_fingerprint(), "sw": sw_fingerprint()}))
 """
 
 
@@ -75,11 +83,10 @@ def run(reps: int = 5, seed: int = 7, cache_root: Optional[str] = None) -> Dict[
     # cold/warm contrast isolates the compilation cache, nothing else.
     defaults = XLA_RUNTIME_SPACE.defaults()
     base = child_env(defaults)
-    cache_dir = persistent_cache_dir(cache_root)
 
     cold_env = dict(base)
     cold_env[ENV_DISABLE] = "off"
-    cold_env.pop(ENV_CACHE_DIR, None)
+    cold_env.pop(ENV_CACHE_DIR, None)  # JAX itself would open a cache there
     warm_env = dict(base)
     warm_env.pop(ENV_DISABLE, None)
     if cache_root:
@@ -87,8 +94,9 @@ def run(reps: int = 5, seed: int = 7, cache_root: Optional[str] = None) -> Dict[
 
     print(f"  cold: {reps} fresh interpreters, persistent cache disabled")
     cold = _first_steps(cold_env, reps)
-    print(f"  priming {cache_dir} (unmeasured)")
-    _run_child(warm_env)
+    prime = _run_child(warm_env)
+    cache_dir = prime["cache_dir"]
+    print(f"  primed {cache_dir} (unmeasured)")
     print(f"  warm: {reps} fresh interpreters against the populated cache")
     warm = _first_steps(warm_env, reps)
 
@@ -110,14 +118,17 @@ def run(reps: int = 5, seed: int = 7, cache_root: Optional[str] = None) -> Dict[
     winner, win_s, lose_s = ((candidate, cand_s, warm_s)
                              if flag_cmp.verdict == "improved"
                              else (defaults, warm_s, cand_s))
+    ctx = configstore.Context(COMPONENT, configstore.WILDCARD,
+                              prime["hardware"], prime["sw"])
     promoted = promote_xla_settings(
-        winner, baseline=lose_s, samples=win_s,
+        winner, baseline=lose_s, samples=win_s, context=ctx,
         provenance={"source": "compile_cold_warm", "metric": "first_step_s",
                     "flag_verdict": flag_cmp.verdict, "seed": seed})
     configstore.invalidate_cache()
-    resolved = resolve_xla_settings()
-    entry = configstore.default_store().resolve_entry(
-        configstore.context_for(COMPONENT))
+    resolved = configstore.resolve_settings(
+        COMPONENT, configstore.WILDCARD, defaults=defaults,
+        hardware=ctx.hardware, sw=ctx.sw)
+    entry = configstore.default_store().resolve_entry(ctx)
     assert promoted, "xla_runtime promotion was gated out against its own loser"
     assert entry is not None, "no stored xla_runtime entry after promotion"
     assert {k: resolved[k] for k in winner} == dict(winner), (resolved, winner)
@@ -129,7 +140,8 @@ def run(reps: int = 5, seed: int = 7, cache_root: Optional[str] = None) -> Dict[
         "seed": seed, "reps": reps,
         "cold_s": cold_s, "warm_s": warm_s,
         "verdict": cmp.to_dict(),
-        "cache_dir": str(cache_dir),
+        "cache_dir": cache_dir,
+        "hardware": prime["hardware"], "sw": prime["sw"],
         "counters": warm[-1]["counters"],
         "xla_runtime": {
             "default": defaults, "candidate": candidate,
@@ -156,14 +168,12 @@ def bench(quick: bool = False, seed: int = 7) -> List[Any]:
     # the median-permutation test at alpha=0.05; with 5v5 the test's
     # granularity leaves p hovering right at the threshold.
     res = _write(run(reps=6 if quick else 7, seed=seed), quick)
-    return [
-        BenchRecord.for_component(
-            "compile_cold_warm", "first_step_cold_s", res["cold_s"],
-            "compilecache", "train_first_step", unit="s"),
-        BenchRecord.for_component(
-            "compile_cold_warm", "first_step_warm_s", res["warm_s"],
-            "compilecache", "train_first_step", unit="s"),
-    ]
+    # keyed by the children's fingerprints: this process has no backend
+    ctx = configstore.Context("compilecache", "train_first_step",
+                              res["hardware"], res["sw"])
+    return [BenchRecord("compile_cold_warm", metric, res[key], ctx, unit="s")
+            for metric, key in (("first_step_cold_s", "cold_s"),
+                                ("first_step_warm_s", "warm_s"))]
 
 
 def main() -> Dict[str, Any]:
@@ -171,7 +181,7 @@ def main() -> Dict[str, Any]:
     ap.add_argument("--quick", action="store_true", help="smoke budget")
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--cache-root", default=None,
-                    help="override the persistent cache root (tests)")
+                    help="the warm children's JAX_COMPILATION_CACHE_DIR")
     args = ap.parse_args()
     return _write(run(reps=6 if args.quick else 7, seed=args.seed,
                       cache_root=args.cache_root), args.quick)

@@ -237,9 +237,11 @@ def run(quick: bool = False, seed: int = 7) -> Dict[str, Any]:
     res: Dict[str, Any] = {"quick": quick, "seed": seed}
     with tempfile.TemporaryDirectory() as td:
         d = Path(td)
+        # every phase that spawns children runs before this process first
+        # touches JAX (torn fallback, overhead): a chip has one owner
         res["train"] = _kill_resume_exact(d, seed, quick)
-        res["torn"] = _torn_fallback(d, seed)
         res["campaign"] = _campaign_no_replay(d, seed)
+        res["torn"] = _torn_fallback(d, seed)
     res["ckpt_overhead"] = _ckpt_overhead(seed, quick)
     res["wall_s"] = time.time() - t0
 

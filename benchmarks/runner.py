@@ -1,8 +1,9 @@
 """Unified benchmark runner: one protocol, one record schema, one gate.
 
 Every registered benchmark exposes ``bench(quick, seed) -> [BenchRecord]``;
-this runner discovers and runs them, gates each record against its stored
-context-keyed baseline distribution (``repro.core.baseline``), appends the
+this runner runs each in a child interpreter of its own, gates each record
+against its stored context-keyed baseline distribution
+(``repro.core.baseline``), appends the
 run to ``results/bench/trajectory.jsonl`` so it becomes the next run's
 baseline, and writes a machine-readable ``results/bench/gate_report.json``.
 
@@ -17,77 +18,53 @@ passes, a planted 2x slowdown fails.  A run with no stored history reads
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
+import os
+import subprocess
 import sys
+import tempfile
 from pathlib import Path
-from typing import Any, Callable, Dict, List
+from typing import Any, Dict, List
 
 from repro.core.baseline import BaselineStore, BenchRecord, TRAJECTORY_PATH
 
-# name -> bench(quick, seed) -> List[BenchRecord].  Import inside the thunk:
-# a benchmark with a broken import must not take down the whole runner list.
-REGISTRY: Dict[str, Callable[[bool, int], List[BenchRecord]]] = {}
+ROOT = Path(__file__).resolve().parents[1]
+
+# name -> module exposing bench(quick, seed) -> List[BenchRecord].  Each runs
+# in a child interpreter of its own, one after another: a chip belongs to one
+# process, so this runner never starts a JAX backend, and a benchmark that
+# spawns device-hungry children of its own finds the chip free.
+REGISTRY: Dict[str, str] = {
+    name: f"benchmarks.{name}" for name in (
+        "optimizer_throughput", "configstore_roundtrip", "multi_instance",
+        "kernel_autotune", "campaign_sweep", "compile_cold_warm",
+        "serve_scenarios", "online_tuning", "fault_tolerance")
+}
 
 
-def register(name: str):
-    def deco(fn):
-        REGISTRY[name] = fn
-        return fn
-    return deco
+def run_child(module: str, *, quick: bool, seed: int) -> List[BenchRecord]:
+    """Run ``module.bench(quick, seed)`` in a fresh interpreter; its records
+    come back through a JSON file."""
+    with tempfile.TemporaryDirectory() as td:
+        out = Path(td) / "records.json"
+        argv = [sys.executable, "-m", "benchmarks.runner", "--child", module,
+                "--seed", str(seed), "--records-out", str(out)]
+        if quick:
+            argv.append("--quick")
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (str(ROOT), str(ROOT / "src"), env.get("PYTHONPATH")) if p)
+        r = subprocess.run(argv, env=env)
+        if r.returncode != 0:
+            raise RuntimeError(f"benchmark {module} exited {r.returncode}")
+        return [BenchRecord.from_dict(d) for d in json.loads(out.read_text())]
 
 
-@register("optimizer_throughput")
-def _optimizer_throughput(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import optimizer_throughput as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("configstore_roundtrip")
-def _configstore_roundtrip(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import configstore_roundtrip as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("multi_instance")
-def _multi_instance(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import multi_instance as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("kernel_autotune")
-def _kernel_autotune(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import kernel_autotune as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("campaign_sweep")
-def _campaign_sweep(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import campaign_sweep as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("compile_cold_warm")
-def _compile_cold_warm(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import compile_cold_warm as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("serve_scenarios")
-def _serve_scenarios(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import serve_scenarios as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("online_tuning")
-def _online_tuning(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import online_tuning as m
-    return m.bench(quick=quick, seed=seed)
-
-
-@register("fault_tolerance")
-def _fault_tolerance(quick: bool, seed: int) -> List[BenchRecord]:
-    from . import fault_tolerance as m
-    return m.bench(quick=quick, seed=seed)
+def _child_main(module: str, *, quick: bool, seed: int, records_out: str) -> int:
+    records = importlib.import_module(module).bench(quick=quick, seed=seed)
+    Path(records_out).write_text(json.dumps([r.to_dict() for r in records]))
+    return 0
 
 
 # Post-run smoke assertions (shared with test.sh --bench-smoke and CI):
@@ -121,7 +98,7 @@ def run_and_gate(names: List[str], *, quick: bool, seed: int, gate: bool,
                               "alpha": alpha, "results": [], "ok": True}
     for name in names:
         print(f"\n=== {name} " + "=" * max(1, 60 - len(name)))
-        records = REGISTRY[name](quick, seed)
+        records = run_child(REGISTRY[name], quick=quick, seed=seed)
         if smoke and name in SMOKE_CHECKS:
             from . import check_bench
             check_bench.run_checks([SMOKE_CHECKS[name]], expect_quick=quick or None)
@@ -171,7 +148,13 @@ def main() -> int:
                     help="skip the check_bench smoke assertions")
     ap.add_argument("--list", action="store_true",
                     help="list registered benchmarks and exit")
+    ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--records-out", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
+
+    if args.child:
+        return _child_main(args.child, quick=args.quick, seed=args.seed,
+                           records_out=args.records_out)
 
     if args.list:
         for name in REGISTRY:

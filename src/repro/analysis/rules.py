@@ -381,8 +381,9 @@ _X64_CTORS = ("array", "asarray", "zeros", "ones", "full", "eye", "arange", "lin
 class RejitHazard(Rule):
     """Engine DESIGN rules: (1) history-dependent buffer shapes bucket at
     powers of two (``bucket_of``) — a ``len(history)``-sized array re-jits on
-    every observation; (2) engine math is float64 under ``enable_x64`` —
-    device arrays built outside the context silently downcast to f32."""
+    every observation; (2) engine math is float64 under ``enable_x64``
+    (``repro.compat``'s, or a module's own wrapper of it) — device arrays
+    built outside the context silently downcast to f32."""
 
     id = "MLOS005"
     name = "rejit-hazard"
@@ -394,7 +395,7 @@ class RejitHazard(Rule):
             return []
         out: List[Finding] = []
         out.extend(self._check_len_shapes(mod, imports))
-        if any(v == "jax.experimental.enable_x64" for v in imports.values()):
+        if self._uses_x64(mod.tree):
             out.extend(self._check_x64(mod, imports))
         return out
 
@@ -461,6 +462,18 @@ class RejitHazard(Rule):
                            "device-array construction outside `with enable_x64():` in an "
                            "x64-engine module: values silently downcast to f32"))
         return out
+
+    @staticmethod
+    def _uses_x64(tree: ast.Module) -> bool:
+        """The module imports or defines an ``enable_x64`` (import style and
+        relative imports aside — the name is the contract)."""
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and any(
+                    a.name == "enable_x64" for a in node.names):
+                return True
+            if isinstance(node, ast.FunctionDef) and node.name == "enable_x64":
+                return True
+        return False
 
     @staticmethod
     def _under_x64(parents: List[ast.AST]) -> bool:

@@ -1,144 +1,74 @@
-"""JAX version-compatibility layer — one place that absorbs API drift.
+"""The JAX API surface that drifts between releases, in one module.
 
-The repo targets the installed JAX (0.4.37 in this container) *and* the
-modern ≥0.5 API, whose mesh constructors changed shape twice:
-
-  * ``jax.make_mesh(shape, axes)`` grew an ``axis_types=`` kwarg and the
-    public ``jax.sharding.AxisType`` enum (0.4.x has only the private
-    ``jax._src.mesh.AxisTypes``, and ``make_mesh`` rejects the kwarg);
-  * ``jax.sharding.AbstractMesh`` flipped from the 0.4.x pair signature
-    ``AbstractMesh((("data", 16), ("model", 16)))`` to the positional
-    ``AbstractMesh((16, 16), ("data", "model"))``.
-
-Per Performance-oriented-DevOps doctrine (and the MLOS paper's "context
-changes ⇒ repeated work" complaint), version probes live *here only*:
-``launch/mesh.py``, ``parallel/sharding.py``, and the distributed/sharding
-tests all build meshes through these helpers, so the next JAX bump is a
-one-file patch.  Everything is feature-detected (try/except), never
-version-string compared, so unreleased intermediates also work.
+The repo runs on one installed JAX (0.9.0).  Mesh constructors,
+``shard_map``, 64-bit mode and the persistent compilation cache have all
+changed shape across JAX releases, so code elsewhere reaches them through
+these helpers (lint rule MLOS001 enforces it): the next JAX bump is a
+one-file patch, and no call site sniffs versions.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional, Sequence
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-__all__ = ["axis_type_auto", "make_mesh", "abstract_mesh", "mesh_axis_sizes", "shard_map",
+__all__ = ["make_mesh", "abstract_mesh", "mesh_axis_sizes", "shard_map", "enable_x64",
            "enable_compilation_cache", "reset_compilation_cache"]
 
 
-def axis_type_auto() -> Optional[Any]:
-    """The public ``AxisType.Auto`` enum member, or ``None`` where the enum
-    does not exist (≤0.4.x — mesh axes are implicitly auto there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return axis_type.Auto if axis_type is not None else None
-
-
-def make_mesh(
-    shape: Sequence[int],
-    axes: Sequence[str],
-    *,
-    devices: Optional[Sequence[Any]] = None,
-) -> Mesh:
-    """``jax.make_mesh`` across versions; axes are always Auto-typed."""
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    auto = axis_type_auto()
-    if auto is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=(auto,) * len(tuple(axes)), **kwargs)
-        except TypeError:  # enum exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto-typed."""
+    axes = tuple(axes)
+    kwargs: Dict[str, Any] = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]) -> AbstractMesh:
-    """``AbstractMesh`` across the positional (≥0.5) / pair (0.4.x) signatures."""
-    shape = tuple(shape)
-    axes = tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """A device-free mesh of the given shape (sharding rules, tests)."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def mesh_axis_sizes(mesh: Any) -> Dict[str, int]:
-    """``{axis_name: size}`` for Mesh and AbstractMesh alike, all versions.
-
-    ``mesh.shape`` is an (Ordered)dict on every lineage so far; the
-    ``shape_tuple`` fallback guards against it becoming a bare tuple.
-    """
-    try:
-        return dict(mesh.shape)  # (Ordered)dict / mapping-like
-    except (TypeError, ValueError):
-        return {name: size for name, size in mesh.shape_tuple}
+    """``{axis_name: size}`` for Mesh and AbstractMesh alike."""
+    return dict(mesh.shape)
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
+def shard_map(f: Any, *, mesh: Any, in_specs: Any, out_specs: Any,
+              check_vma: bool = True) -> Any:
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
+
+
+def enable_x64() -> contextlib.AbstractContextManager:
+    """Context manager: arrays built and programs traced inside it are 64-bit;
+    outside it the process keeps JAX's 32-bit default."""
+    return jax.enable_x64(True)
+
+
+def enable_compilation_cache(cache_dir: str) -> None:
     """Point JAX's persistent compilation cache at ``cache_dir``.
 
-    The cache API drifted: the config-key lineage exposes
-    ``jax.config.update("jax_compilation_cache_dir", ...)``, while older
-    lineages route through ``jax.experimental.compilation_cache``'s
-    ``set_cache_dir`` / ``initialize_cache``.  Both are probed (try/except,
-    never version-compared); returns True when some lineage accepted the
-    directory, False when none did — callers degrade to cold compiles, they
-    never crash on a missing cache.
+    A no-op when JAX already uses that directory, as it does when
+    ``JAX_COMPILATION_CACHE_DIR`` names it.  The persistence thresholds stay
+    at JAX's defaults (compiles of a second or more): the train, prefill and
+    decode steps, where the cold-restart cost lives, all clear them.
     """
-    enabled = False
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        enabled = True
-    except (AttributeError, KeyError, ValueError):
-        pass  # config key predates this lineage: fall through to the module API
-    if not enabled:
-        try:
-            from jax.experimental.compilation_cache import compilation_cache as cc
-        except ImportError:
-            return False
-        init = getattr(cc, "set_cache_dir", None) or getattr(cc, "initialize_cache", None)
-        if init is None:
-            return False
-        try:
-            init(str(cache_dir))
-        except Exception:  # noqa: BLE001 — a broken cache backend must not take the host down
-            return False
-    # The persistence thresholds stay at their defaults (min compile time
-    # 1s) ON PURPOSE: forcing every sub-second executable into the cache
-    # makes a warm process deserialize dozens of tiny CPU executables, which
-    # intermittently aborts inside jaxlib 0.4.37 (native crash, ~50% per run
-    # on the tier-1 suite).  The ≥1s traces — train/prefill/decode steps —
-    # are where the cold-restart cost lives anyway; microbench candidates
-    # recompile in well under the time a crashed host costs.
-    # If a compile already ran, the cache module latched "no cache dir" at
-    # backend init and setting the config afterwards is a silent no-op; drop
-    # the latched handle so the next compile re-reads the directory.
+    if jax.config.jax_compilation_cache_dir == cache_dir:
+        return
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # A compile that ran before this call latched "no cache"; drop the
+    # latched handle so the next compile opens the directory.
     reset_compilation_cache()
-    return True
 
 
 def reset_compilation_cache() -> None:
     """Drop the in-memory cache handle so the next compile re-reads the
     configured directory (tests switch cache dirs in-process)."""
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as cc
-        cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-
-def shard_map(f: Any, *, mesh: Any, in_specs: Any, out_specs: Any,
-              check_vma: bool = True) -> Any:
-    """``jax.shard_map`` (≥0.5, ``check_vma=``) or the 0.4.x
-    ``jax.experimental.shard_map.shard_map`` (``check_rep=`` — same switch,
-    renamed when replication checking became varying-manual-axes checking)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as fn_old
-    return fn_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    cc.reset_cache()

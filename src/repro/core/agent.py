@@ -469,7 +469,14 @@ def agent_main(
     defaults (e.g. ``optimizer.backend=jax`` from launch/tuning) into this
     freshly *spawned* interpreter — without it, sessions naming a generic
     optimizer ("bo") would silently fall back to the module defaults.
+
+    The daemon's JAX is pinned to the CPU before any backend starts: the
+    host process holds the accelerator (a chip belongs to one process), and
+    the optimizers compute on the host anyway.
     """
+    import jax  # deferred: the rest of this module needs no JAX
+
+    jax.config.update("jax_platforms", "cpu")
     if optimizer_defaults_json:
         set_optimizer_defaults(**json.loads(optimizer_defaults_json))
     chan = MlosChannel.attach(telemetry_name, control_name)

@@ -70,6 +70,18 @@ class BenchRecord:
         return BenchRecord(benchmark, metric, [float(v) for v in values], ctx,
                            mode=mode, unit=unit, meta=dict(meta))
 
+    def to_dict(self) -> Dict[str, Any]:
+        return {"benchmark": self.benchmark, "metric": self.metric,
+                "values": [float(v) for v in self.values],
+                "context": self.context.to_dict(), "mode": self.mode,
+                "unit": self.unit, "meta": self.meta}
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "BenchRecord":
+        return BenchRecord(d["benchmark"], d["metric"], list(d["values"]),
+                           Context.from_dict(d["context"]), mode=d["mode"],
+                           unit=d["unit"], meta=dict(d["meta"]))
+
 
 @dataclasses.dataclass(frozen=True)
 class GateReport:
@@ -121,20 +133,9 @@ class BaselineStore:
         ts = time.time() if timestamp is None else timestamp
         rows = []
         for r in records:
-            rows.append({
-                "schema": SCHEMA_VERSION,
-                "benchmark": r.benchmark,
-                "metric": r.metric,
-                "values": [float(v) for v in r.values],
-                "context": r.context.to_dict(),
-                "mode": r.mode,
-                "unit": r.unit,
-                "quick": bool(quick),
-                "git_sha": sha,
-                "timestamp": ts,
-                "run_id": run_id,
-                "meta": r.meta,
-            })
+            rows.append({"schema": SCHEMA_VERSION, **r.to_dict(),
+                         "quick": bool(quick), "git_sha": sha,
+                         "timestamp": ts, "run_id": run_id})
         self.path.parent.mkdir(parents=True, exist_ok=True)
         blob = "".join(json.dumps(row) + "\n" for row in rows)
         fd = os.open(self.path, os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)

@@ -7,8 +7,9 @@ runtime flags that gate codegen quality were hardcoded env pokes outside the
 tuning loop.  This module closes both gaps (ROADMAP item 3, fronts b/c):
 
   * :func:`enable_persistent_cache` wires JAX's persistent compilation cache
-    (through the :mod:`repro.compat` shim — the cache API drifted) at
-    ``results/compilecache/<hw>/<sw>``, namespaced by the same
+    (through the :mod:`repro.compat` shim).  Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the cache lives exactly there; otherwise at
+    ``<checkout>/results/compilecache/<hw>/<sw>``, namespaced by the same
     hardware-fingerprint × software-version coordinates as the ConfigStore,
     so a tuned (config, shape-bucket) pair never recompiles across processes
     — and an entry compiled under different coordinates is never reused.
@@ -39,8 +40,8 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Dict, Hashable, List, Mapping, MutableMapping, Optional
 
-from .configstore import WILDCARD, context_for, default_store, hardware_fingerprint, \
-    resolve_settings, sw_fingerprint
+from .configstore import WILDCARD, Context, context_for, default_store, \
+    hardware_fingerprint, resolve_settings, sw_fingerprint
 from .tunable import Bool, Int, TunableSpace
 
 __all__ = [
@@ -53,11 +54,13 @@ __all__ = [
 ]
 
 COMPONENT = "xla_runtime"
-CACHE_ROOT = "results/compilecache"
-# Kill switches / overrides (read at first use, so benchmark children can
-# flip them without code changes):
-ENV_DISABLE = "REPRO_COMPILECACHE"       # "off"/"0"/"false" disables persistence
-ENV_CACHE_DIR = "REPRO_COMPILECACHE_DIR"  # overrides the cache root
+# The checkout this package runs from: the default cache root hangs off it,
+# so the cache path is the same from any working directory.
+CHECKOUT = Path(__file__).resolve().parents[3]
+CACHE_ROOT = CHECKOUT / "results" / "compilecache"
+# Read at first use, so benchmark children can flip them without code changes:
+ENV_DISABLE = "REPRO_COMPILECACHE"           # "off"/"0"/"false" disables persistence
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"  # JAX's own: when set, the cache lives there
 
 
 # =============================================================================
@@ -71,11 +74,16 @@ def _sanitize(s: str) -> str:
     return _SANITIZE.sub("-", s).strip("-") or "unknown"
 
 
-def persistent_cache_dir(root: Optional[str] = None) -> Path:
-    """Where this process's compiled executables live: the configured root
+def persistent_cache_dir() -> Path:
+    """Where this process's compiled executables live.
+
+    Exactly ``$JAX_COMPILATION_CACHE_DIR`` when it is set (whoever set it
+    owns the layout).  Otherwise ``<checkout>/results/compilecache``,
     namespaced by the ConfigStore's hardware × software coordinates."""
-    base = root or os.environ.get(ENV_CACHE_DIR) or CACHE_ROOT
-    return Path(base) / _sanitize(hardware_fingerprint()) / _sanitize(sw_fingerprint())
+    env = os.environ.get(ENV_CACHE_DIR)
+    if env:
+        return Path(env)
+    return CACHE_ROOT / _sanitize(hardware_fingerprint()) / _sanitize(sw_fingerprint())
 
 
 _CACHE_LOCK = threading.Lock()
@@ -87,28 +95,29 @@ def _disabled() -> bool:
     return os.environ.get(ENV_DISABLE, "").strip().lower() in ("off", "0", "false", "no")
 
 
-def enable_persistent_cache(root: Optional[str] = None) -> Optional[Path]:
+def enable_persistent_cache() -> Optional[Path]:
     """Idempotently enable the persistent compilation cache; returns the
     active cache directory, or None when disabled (``REPRO_COMPILECACHE=off``)
-    or unsupported by the installed JAX.  Safe to call from anywhere on the
-    jit path — the first caller wins, later calls are a no-op."""
+    or not writable.  Safe to call from anywhere on the jit path — the first
+    caller wins, later calls are a no-op."""
     global _CACHE_DIR, _CACHE_TRIED
     if _disabled():
         return None
     with _CACHE_LOCK:
-        if _CACHE_TRIED and root is None:
+        if _CACHE_TRIED:
             return _CACHE_DIR
-        d = persistent_cache_dir(root)
+        d: Optional[Path] = persistent_cache_dir()
         from .. import compat  # lazy: compat imports jax
 
         try:
             d.mkdir(parents=True, exist_ok=True)
-            ok = compat.enable_compilation_cache(str(d))
         except OSError:
-            ok = False  # unwritable root: degrade to cold compiles
+            d = None  # unwritable root: degrade to cold compiles
+        else:
+            compat.enable_compilation_cache(str(d))
         _CACHE_TRIED = True
-        _CACHE_DIR = d if ok else None
-        return _CACHE_DIR
+        _CACHE_DIR = d
+        return d
 
 
 # =============================================================================
@@ -174,14 +183,14 @@ def cached_jit(fn: Callable, *, key: str, context: Hashable = None,
     The first use also wires the persistent compilation cache, so the miss
     path's XLA compile is itself served from disk on repeat runs.
 
-    ``donate_argnums`` and ``persistent=True`` are mutually exclusive: the
-    CPU runtime in this container mis-handles ``input_output_aliases`` on a
-    *deserialized* executable — the donated buffer is freed while the aliased
-    output is still live, and the next touch is a heap-corrupting
-    use-after-free (intermittent SIGSEGV/SIGABRT, timing dependent).  Each
-    jit site picks one: donate on hot in-process loops that never restart
-    (serve decode), persist on the expensive traces where cold restarts hurt
-    (train/prefill steps)."""
+    ``donate_argnums`` and ``persistent=True`` are mutually exclusive: XLA's
+    CPU runtime under an earlier jaxlib mis-handled ``input_output_aliases``
+    on a *deserialized* executable — the donated buffer was freed while the
+    aliased output was still live, a heap-corrupting use-after-free
+    (intermittent SIGSEGV/SIGABRT).  No run has shown the installed JAX free
+    of it, so each jit site picks one: donate on hot in-process loops that
+    never restart (serve decode), persist on the expensive traces where cold
+    restarts hurt (train/prefill steps)."""
     if donate_argnums and persistent:
         raise ValueError(
             f"cached_jit({key!r}): donate_argnums with persistent=True would "
@@ -349,12 +358,15 @@ def promote_xla_settings(settings: Mapping[str, Any], *,
                          samples: Optional[List[float]] = None,
                          mode: str = "min",
                          provenance: Optional[Dict[str, Any]] = None,
-                         store: Any = None) -> bool:
-    """Validated write of tuned flags under this host's hardware-fingerprint
-    context: the entry persists only if the ``stats.compare`` gate doesn't
-    call it a significant regression vs ``baseline`` (the normal
-    ``ConfigStore.promote`` machinery; verdict recorded in provenance)."""
+                         store: Any = None,
+                         context: Optional[Context] = None) -> bool:
+    """Validated write of tuned flags under a hardware-fingerprint context
+    (default: this process's): the entry persists only if the
+    ``stats.compare`` gate doesn't call it a significant regression vs
+    ``baseline`` (the normal ``ConfigStore.promote`` machinery; verdict
+    recorded in provenance).  A launcher that measured in children, and
+    starts no backend itself, passes the children's ``context``."""
     store = store if store is not None else default_store()
     kv = XLA_RUNTIME_SPACE.validate(dict(settings))
-    return store.promote(context_for(COMPONENT), kv, baseline=baseline,
+    return store.promote(context or context_for(COMPONENT), kv, baseline=baseline,
                          samples=samples, mode=mode, provenance=dict(provenance or {}))

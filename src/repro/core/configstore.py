@@ -37,7 +37,6 @@ import itertools
 import json
 import math
 import os
-import platform
 import re
 import sys
 import tempfile
@@ -71,15 +70,16 @@ def bucket_pow2(n: int) -> int:
 
 @functools.lru_cache(maxsize=1)
 def hardware_fingerprint() -> str:
-    """Backend + device kind + device count of this process's accelerator."""
-    try:
-        import jax
+    """Backend + device kind + device count of this process's accelerator.
 
-        dev = jax.devices()[0]
-        kind = str(getattr(dev, "device_kind", "unknown")).replace(" ", "_")
-        return f"{jax.default_backend()}:{kind}:x{jax.device_count()}"
-    except Exception:  # noqa: BLE001 — fingerprinting must never fail a lookup
-        return f"host:{platform.machine()}:x1"
+    Starts the JAX backend.  A failed device lookup raises: a made-up
+    fingerprint would file tuned entries under hardware that did not run
+    them."""
+    import jax
+
+    dev = jax.devices()[0]
+    kind = str(dev.device_kind).replace(" ", "_")
+    return f"{jax.default_backend()}:{kind}:x{jax.device_count()}"
 
 
 @functools.lru_cache(maxsize=1)

@@ -36,9 +36,11 @@ This module is the production backend:
     ``pmap``: measured slower on the CPU backend — see
     :func:`_batched_suggest_fn`.)
 
-Everything runs in float64 under ``jax.experimental.enable_x64`` — Cholesky
-at jitter 1e-8 is not float32-safe, and parity with the numpy reference is a
-tested contract, not an aspiration.  Compiled functions are cached per
+Everything runs in float64 on the host CPU, under this module's
+:func:`enable_x64` — Cholesky at jitter 1e-8 is not float32-safe, parity
+with the numpy reference is a tested contract, not an aspiration, and on a
+TPU host the default device is the chip, which belongs to the model the
+process serves or trains.  Compiled functions are cached per
 (kernel[, acq]) by ``lru_cache``; jit's own cache keys the rest on the
 (d, n_bucket, pool) shapes — and the batched session axis is padded to a
 power of two — so every shape family compiles O(1) programs, never one per
@@ -46,6 +48,7 @@ observation count or per ready-session count.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -55,9 +58,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 from jax.scipy.linalg import solve_triangular
 from jax.scipy.stats import norm as jnorm
+
+from ... import compat
 
 __all__ = ["JaxGP", "BatchedBayesOpt", "batched_ask", "bucket_of", "MIN_BUCKET"]
 
@@ -70,6 +74,15 @@ _ADAM_LR = 0.08
 _THETA_LO = (-4.6, -4.6, -13.8)
 _THETA_HI = (2.3, 4.6, 0.0)
 _LS_STARTS = (0.1, 0.3, 1.0)
+
+
+@contextlib.contextmanager
+def enable_x64():
+    """The engine's compute context: arrays built and programs run inside it
+    are float64 and live on the host CPU (``compat.enable_x64`` plus the CPU
+    as default device)."""
+    with compat.enable_x64(), jax.default_device(jax.devices("cpu")[0]):
+        yield
 
 
 def bucket_of(n: int) -> int:

@@ -15,6 +15,7 @@ import jax
 from ...core.configstore import bucket_pow2
 from ...core.registry import MetricSpec, tunable_component
 from ...core.tunable import Categorical, Int
+from .. import require_tpu
 from . import ref
 
 __all__ = ["flash_attention", "decode_attention", "attention_settings",
@@ -82,14 +83,8 @@ def flash_attention(
             block_q=block_q, block_kv=block_kv, exact_prefix=impl == "unrolled",
         )
     if impl == "pallas":
-        if jax.default_backend() != "tpu":
-            # Mosaic kernels only lower on TPU: off-TPU the op transparently
-            # falls back to the FLOP-identical unrolled path (the dry-run's
-            # roofline models the kernel's VMEM-residency — launch/adjust.py)
-            return ref.unrolled_attention(
-                q, k, v, causal=causal, window=window, q_offset=q_offset,
-                block_q=block_q, block_kv=block_kv)
-        from . import kernel  # lazy: pallas import only on TPU
+        require_tpu("flash_attention")
+        from . import kernel
 
         return kernel.flash_attention_pallas(
             q, k, v, causal=causal, window=window, q_offset=q_offset,

@@ -32,7 +32,7 @@ def _kernel_res(x_ref, r_ref, s_ref, o_ref, *, eps: float, out_dtype):
 
 def rmsnorm_pallas(x: jax.Array, scale: jax.Array, residual: Optional[jax.Array] = None,
                    eps: float = 1e-5, block_rows: int = 256,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """x: (..., d); scale: (d,). Returns rmsnorm(x [+ residual]) * scale."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -42,8 +42,6 @@ def rmsnorm_pallas(x: jax.Array, scale: jax.Array, residual: Optional[jax.Array]
     while rows % block_rows:
         block_rows //= 2
     block_rows = max(block_rows, 1)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid = (rows // block_rows,)
     x_spec = pl.BlockSpec((block_rows, d), lambda i: (i, 0))

@@ -8,6 +8,7 @@ import jax
 from ...core.configstore import bucket_pow2
 from ...core.registry import MetricSpec, tunable_component
 from ...core.tunable import Categorical, Int
+from .. import require_tpu
 from . import ref
 
 __all__ = ["rmsnorm", "rmsnorm_settings", "RmsNormSettings", "workload_signature"]
@@ -46,6 +47,7 @@ def rmsnorm(x: jax.Array, scale: jax.Array, residual: Optional[jax.Array] = None
     impl = impl or s["impl"]
     if impl == "jnp":
         return ref.rmsnorm(x, scale, residual, eps)
+    require_tpu("rmsnorm")
     from . import kernel
 
     return kernel.rmsnorm_pallas(x, scale, residual, eps,

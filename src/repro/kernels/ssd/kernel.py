@@ -27,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ssd_pallas"]
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
+def _kernel(x_ref, dt_ref, csc_ref, csr_ref, b_ref, c_ref, o_ref, state_ref, *,
             chunk: int, out_dtype):
     ci = pl.program_id(2)
 
@@ -35,76 +35,84 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)           # (Q,)
-    a = a_ref[0].astype(jnp.float32)                   # scalar A for this head
-    bb = b_ref[0, :, 0, :].astype(jnp.float32)         # (Q, N)
-    cc = c_ref[0, :, 0, :].astype(jnp.float32)         # (Q, N)
+    x = x_ref[...].astype(jnp.float32)                 # (Q, P)
+    dt = dt_ref[...]                                   # (Q, 1)
+    cs = csc_ref[...]                                  # (Q, 1) in-chunk cumsum of dt·A
+    cs_row = csr_ref[...]                              # (1, Q) the same, as a row
+    bb = b_ref[...].astype(jnp.float32)                # (Q, N)
+    cc = c_ref[...].astype(jnp.float32)                # (Q, N)
 
-    la = dt * a                                        # (Q,) log-decay per step
-    cs = jnp.cumsum(la)                                # inclusive
     # intra-chunk decay matrix L[i,j] = exp(cs_i - cs_j) for i >= j
-    li = cs[:, None] - cs[None, :]
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    el = jnp.where(iq >= jq, jnp.exp(li), 0.0)         # (Q, Q)
+    el = jnp.exp(jnp.where(iq >= jq, cs - cs_row, -jnp.inf))   # (Q, Q)
 
     scores = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * el
-    dtx = dt[:, None] * x                              # (Q, P)
+    dtx = dt * x                                       # (Q, P)
     y = jax.lax.dot_general(scores, dtx, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y_i += decay_in_i * C_i · state   (state: (N, P))
-    decay_in = jnp.exp(cs)                             # (Q,)
-    y = y + decay_in[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cs) * jax.lax.dot_general(
         cc, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: state' = e^{total} state + Σ_j decay_out_j B_jᵀ (dt_j x_j)
-    total = cs[-1]
-    decay_out = jnp.exp(total - cs)                    # (Q,)
+    total = csc_ref[chunk - 1, 0]                      # scalar: chunk's total
+    decay_out = jnp.exp(total - cs)                    # (Q, 1)
     state_ref[...] = jnp.exp(total) * state_ref[...] + jax.lax.dot_general(
-        bb * decay_out[:, None], dtx, (((0,), (0,)), ((), ())),
+        bb * decay_out, dtx, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # (N, P)
 
-    o_ref[0, :, 0, :] = y.astype(out_dtype)
+    o_ref[...] = y.astype(out_dtype)
 
 
 def ssd_pallas(
     x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
     D: Optional[jax.Array] = None, *, chunk: int = 128,
-    init_state: Optional[jax.Array] = None, return_state: bool = False,
-    interpret: Optional[bool] = None,
+    return_state: bool = False, interpret: bool = False,
 ):
-    """Shapes as ref.ssd_chunked: x (B,S,H,P); dt (B,S,H); A (H,); B/C (B,S,G,N)."""
+    """Shapes as ref.ssd_chunked: x (B,S,H,P); dt (B,S,H); A (H,); B/C (B,S,G,N).
+
+    Starts from a zero state (prefill).  ``interpret=True`` runs the Pallas
+    interpreter (tests off the TPU)."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     g = B.shape[2]
     if s % chunk:
         raise ValueError(f"seq {s} % chunk {chunk} != 0")
-    if init_state is not None:
-        raise NotImplementedError("ssd_pallas starts from zero state (prefill)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    nc = s // chunk
 
-    grid = (b, h, s // chunk)
+    # Head-major layouts keep every block's two minor dims tile-aligned or
+    # full: x/y (B,H,S,P) and dt (B,H,S,1) tile (chunk, P|1), the log-decay
+    # cumsum (computed here, once per element) rides as a column and as a
+    # row, and B/C fold their group axis into the last dim (N per group).
+    xt = x.transpose(0, 2, 1, 3)
+    dtf = dt.astype(jnp.float32).transpose(0, 2, 1)                  # (B,H,S)
+    cs = jnp.cumsum((dtf * A.astype(jnp.float32)[None, :, None])
+                    .reshape(b, h, nc, chunk), axis=-1)
+    grid = (b, h, nc)
+    sq = pl.Squeezed()
     kern = functools.partial(_kernel, chunk=chunk, out_dtype=x.dtype)
     y = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci, g=g: (bi, ci, hi // (h // g), 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci, g=g: (bi, ci, hi // (h // g), 0)),
+            pl.BlockSpec((sq, sq, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((sq, sq, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((sq, sq, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((sq, sq, sq, 1, chunk), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            pl.BlockSpec((sq, chunk, n), lambda bi, hi, ci: (bi, ci, hi // (h // g))),
+            pl.BlockSpec((sq, chunk, n), lambda bi, hi, ci: (bi, ci, hi // (h // g))),
         ],
-        out_specs=pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+        out_specs=pl.BlockSpec((sq, sq, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, B, C)
+    )(xt, dtf.reshape(b, h, s, 1), cs.reshape(b, h, s, 1),
+      cs.reshape(b, h, nc, 1, chunk), B.reshape(b, s, g * n), C.reshape(b, s, g * n))
+    y = y.transpose(0, 2, 1, 3)
 
     if D is not None:
         y = y + (D[None, None, :, None] * x.astype(jnp.float32)).astype(y.dtype)

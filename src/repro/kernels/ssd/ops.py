@@ -3,11 +3,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 from ...core.configstore import bucket_pow2
 from ...core.registry import MetricSpec, tunable_component
 from ...core.tunable import Categorical, Int
+from .. import require_tpu
 from . import ref
 
 __all__ = ["ssd", "ssd_decode_step", "ssd_settings", "SsdKernelSettings", "workload_signature"]
@@ -54,13 +53,15 @@ def ssd(x, dt, A, B, C, D=None, *, impl: Optional[str] = None, chunk: Optional[i
         return ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk, init_state=init_state,
                                return_state=return_state, unroll=impl == "chunked_unrolled")
     if impl == "pallas":
-        if jax.default_backend() != "tpu" or init_state is not None:
-            # off-TPU (or resuming from state) → FLOP-identical chunked path
+        require_tpu("ssd")
+        if init_state is not None:
+            # The kernel cannot resume from a carried state yet (ROADMAP
+            # queue 2 item 6): chunked decode-resume takes the jnp path.
             return ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk,
                                    init_state=init_state, return_state=return_state)
         from . import kernel
 
-        return kernel.ssd_pallas(x, dt, A, B, C, D, chunk=chunk, init_state=init_state, return_state=return_state)
+        return kernel.ssd_pallas(x, dt, A, B, C, D, chunk=chunk, return_state=return_state)
     raise ValueError(f"unknown ssd impl {impl!r}")
 
 

@@ -1,8 +1,10 @@
 #!/usr/bin/env bash
 # Canonical tier-1 test entry point (see ROADMAP.md).
 #
-# Pins the two bits of environment the suite assumes:
+# Pins the bits of environment the suite assumes:
 #   * PYTHONPATH includes src/ (the repo is run from source, not installed);
+#   * JAX_PLATFORMS=cpu unless set: the suite runs on XLA's CPU backend (a
+#     TPU host's chip belongs to chip_smoke.py and the benchmarks);
 #   * XLA_FLAGS requests 8 host platform devices so multi-device semantics
 #     are exercisable on CPU (SNIPPETS.md test.sh idiom).  test_distributed
 #     re-pins its own count inside subprocesses either way, and an existing
@@ -44,6 +46,7 @@ set -euo pipefail
 cd "$(dirname "$0")"
 export PYTHONPATH="$PWD/src${PYTHONPATH:+:$PYTHONPATH}"
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 if [[ "${1:-}" == "--bench-smoke" ]]; then
   shift

@@ -208,7 +208,7 @@ def test_mlos005_fires_on_unbucketed_len_and_unguarded_x64(tmp_path):
             """,
         "src/repro/bad_x64.py": """\
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
+            from repro.compat import enable_x64
 
             def upload(vals):
                 return jnp.asarray(vals)
@@ -241,7 +241,7 @@ def test_mlos005_silent_on_bucketed_and_guarded_twin(tmp_path):
         # constructor outside the with, but every call site is guarded
         "src/repro/good_x64.py": """\
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
+            from repro.compat import enable_x64
 
             def _upload(vals):
                 return jnp.asarray(vals)

@@ -1,6 +1,7 @@
 """Trajectory store + context-keyed baselines + the bench regression gate."""
 import json
 import multiprocessing
+import textwrap
 
 import numpy as np
 import pytest
@@ -132,15 +133,22 @@ def test_gate_verdict_is_reproducible(store):
 def test_runner_gate_fails_on_injected_regression(tmp_path, monkeypatch):
     from benchmarks import runner
 
-    factor = {"x": 1.0}
+    # The runner runs each benchmark in a child interpreter: the synthetic
+    # one is a module on the child's path, its slowdown an env var.
+    (tmp_path / "synthetic_bench.py").write_text(textwrap.dedent("""\
+        import os
+        import numpy as np
+        from repro.core.baseline import BenchRecord
 
-    def synthetic(quick, seed):
-        rng = np.random.default_rng(seed)
-        return [BenchRecord.for_component(
-            "synthetic", "lat_ms", (rng.normal(100, 3, 15) * factor["x"]).tolist(),
-            "comp", "wl0")]
-
-    monkeypatch.setitem(runner.REGISTRY, "synthetic", synthetic)
+        def bench(quick, seed):
+            rng = np.random.default_rng(seed)
+            factor = float(os.environ["SYNTHETIC_FACTOR"])
+            return [BenchRecord.for_component(
+                "synthetic", "lat_ms", (rng.normal(100, 3, 15) * factor).tolist(),
+                "comp", "wl0")]
+        """))
+    monkeypatch.setitem(runner.REGISTRY, "synthetic", "synthetic_bench")
+    monkeypatch.setenv("SYNTHETIC_FACTOR", "1.0")
     monkeypatch.chdir(tmp_path)  # gate_report.json lands under tmp results/
     traj = str(tmp_path / "trajectory.jsonl")
 
@@ -151,7 +159,7 @@ def test_runner_gate_fails_on_injected_regression(tmp_path, monkeypatch):
 
     assert gate(1)["results"][0]["verdict"] == "no_baseline"  # bootstrap run
     assert gate(2)["results"][0]["verdict"] == "noise"        # jitter passes
-    factor["x"] = 2.0
+    monkeypatch.setenv("SYNTHETIC_FACTOR", "2.0")
     rep = gate(3)
     assert rep["results"][0]["verdict"] == "regressed" and not rep["ok"]
     report = json.loads((tmp_path / "results/bench/gate_report.json").read_text())

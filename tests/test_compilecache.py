@@ -6,6 +6,7 @@ from __future__ import annotations
 import json
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -42,40 +43,40 @@ def registry():
 # ------------------------------------------------------------------ compat shim
 def test_compat_modern_branch_sets_config(tmp_path):
     d = str(tmp_path / "cc")
-    assert compat.enable_compilation_cache(d) is True
+    compat.enable_compilation_cache(d)
     assert jax.config.jax_compilation_cache_dir == d
 
 
-def test_compat_legacy_branch_via_module_api(tmp_path, monkeypatch):
-    """When the config key is absent (older lineage), the shim falls through
-    to jax.experimental.compilation_cache's set_cache_dir."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
+def test_cache_dir_env_is_used_as_is(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the cache lives exactly there (no
+    hardware/software subdirectories), and JAX's own config reads it."""
+    d = tmp_path / "jaxcache"
+    child = (
+        "import jax\n"
+        "from repro.core.compilecache import enable_persistent_cache\n"
+        "before = jax.config.jax_compilation_cache_dir\n"
+        "active = enable_persistent_cache()\n"
+        "print(before, active, jax.config.jax_compilation_cache_dir)\n")
+    env = dict(compilecache.child_env({}), JAX_COMPILATION_CACHE_DIR=str(d))
+    env.pop(compilecache.ENV_DISABLE, None)
+    env["PYTHONPATH"] = str(compilecache.CHECKOUT / "src")
+    r = subprocess.run([sys.executable, "-c", child], capture_output=True, text=True,
+                       timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-800:]
+    assert r.stdout.split() == [str(d)] * 3
+    assert d.is_dir() and not any(d.iterdir())
 
-    real_update = jax.config.update
-    calls = {}
 
-    def drifted_update(key, val):
-        if key == "jax_compilation_cache_dir":
-            raise AttributeError(key)  # this lineage predates the config key
-        return real_update(key, val)
-
-    monkeypatch.setattr(jax.config, "update", drifted_update)
-    monkeypatch.setattr(cc, "set_cache_dir",
-                        lambda d: calls.setdefault("dir", d), raising=False)
-    assert compat.enable_compilation_cache(str(tmp_path)) is True
-    assert calls["dir"] == str(tmp_path)
-
-
-def test_compat_no_cache_api_returns_false(tmp_path, monkeypatch):
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    def no_update(key, val):
-        raise AttributeError(key)
-
-    monkeypatch.setattr(jax.config, "update", no_update)
-    monkeypatch.setattr(cc, "set_cache_dir", None, raising=False)
-    monkeypatch.setattr(cc, "initialize_cache", None, raising=False)
-    assert compat.enable_compilation_cache(str(tmp_path)) is False
+def test_cache_dir_default_is_under_checkout_from_any_cwd(tmp_path, monkeypatch):
+    monkeypatch.delenv(compilecache.ENV_CACHE_DIR, raising=False)
+    dirs = []
+    for sub in ("a", "b"):
+        (tmp_path / sub).mkdir()
+        monkeypatch.chdir(tmp_path / sub)
+        dirs.append(compilecache.persistent_cache_dir())
+    assert dirs[0] == dirs[1] and dirs[0].is_absolute()
+    checkout = Path(__file__).resolve().parents[1]
+    assert dirs[0].relative_to(checkout / "results" / "compilecache")
 
 
 # ------------------------------------------------------------------- cached_jit
@@ -229,12 +230,14 @@ def test_child_env_applies_tuned_flags_on_reexec(store):
 
 # --------------------------------------------------- persistent cache plumbing
 def test_persistent_cache_dir_is_context_keyed(tmp_path, monkeypatch):
-    monkeypatch.setenv(compilecache.ENV_CACHE_DIR, str(tmp_path))
-    d = compilecache.persistent_cache_dir()
-    assert str(d).startswith(str(tmp_path))
-    parts = d.relative_to(tmp_path).parts
-    assert len(parts) == 2  # <hw-fingerprint>/<sw-fingerprint>
+    """Unset, the default root is namespaced <hw-fingerprint>/<sw-fingerprint>;
+    set, JAX_COMPILATION_CACHE_DIR is taken as is, with no subdirectories."""
+    monkeypatch.delenv(compilecache.ENV_CACHE_DIR, raising=False)
+    parts = compilecache.persistent_cache_dir().relative_to(compilecache.CACHE_ROOT).parts
+    assert len(parts) == 2
     assert all(p and "/" not in p and ":" not in p for p in parts)
+    monkeypatch.setenv(compilecache.ENV_CACHE_DIR, str(tmp_path))
+    assert compilecache.persistent_cache_dir() == tmp_path
 
 
 def test_env_kill_switch_disables_persistence(monkeypatch):
