@@ -27,7 +27,7 @@ from .registry import MetricSpec, all_components, get_component, tunable_compone
 from .rpi import RPI, Bound, RpiReport, assert_rpi
 from .stats import (Comparison, Measurement, StreamingAB, bootstrap_ci, compare,
                     measure_adaptive, measure_interleaved)
-from .telemetry import Stopwatch, TelemetryEmitter, collective_bytes, hlo_counters, os_counters
+from .telemetry import TelemetryEmitter, collective_bytes, hlo_counters, os_counters, span
 from .tracking import Tracker
 from .tunable import Bool, Categorical, Float, Int, Tunable, TunableSpace
 
@@ -44,7 +44,7 @@ __all__ = [
     "measure_adaptive", "measure_interleaved",
     "MetricSpec", "all_components", "get_component", "tunable_component",
     "RPI", "Bound", "RpiReport", "assert_rpi",
-    "Stopwatch", "TelemetryEmitter", "collective_bytes", "hlo_counters", "os_counters",
+    "TelemetryEmitter", "collective_bytes", "hlo_counters", "os_counters", "span",
     "Tracker",
     "Bool", "Categorical", "Float", "Int", "Tunable", "TunableSpace",
 ]

@@ -15,10 +15,10 @@ tuning loop.  This module closes both gaps (ROADMAP item 3, fronts b/c):
     — and an entry compiled under different coordinates is never reused.
   * :func:`cached_jit` is the process-local jit registry: compiled callables
     memoized by an explicit key + config-store context signature, with
-    hit/miss/compile-seconds counters exported via ``core.telemetry``.  The
-    serve decode step, the train step, and kernel-autotune candidates all
-    route through it — new jitted hot paths should too, instead of bare
-    ``jax.jit`` at call sites.
+    hit/miss counters and the compiles JAX reports (count, seconds, per
+    function) exported via ``core.telemetry``.  The serve decode step, the
+    train step, and kernel-autotune candidates all route through it — new
+    jitted hot paths should too, instead of bare ``jax.jit`` at call sites.
   * The ``xla_runtime`` pseudo-component (:data:`XLA_RUNTIME_SPACE`) makes
     the host-relevant XLA flag surface a declared tunable space, resolved /
     promoted through the normal ConfigStore + ``stats.compare`` machinery
@@ -38,7 +38,8 @@ import re
 import threading
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, Hashable, List, Mapping, MutableMapping, Optional
+from typing import Any, Callable, Dict, Hashable, List, Mapping, MutableMapping, Optional, \
+    Tuple
 
 from .configstore import WILDCARD, Context, context_for, default_store, \
     hardware_fingerprint, resolve_settings, sw_fingerprint
@@ -47,7 +48,7 @@ from .tunable import Bool, Int, TunableSpace
 __all__ = [
     "COMPONENT", "XLA_RUNTIME_SPACE",
     "enable_persistent_cache", "persistent_cache_dir", "cache_counters",
-    "cached_jit", "clear_jit_registry", "config_signature",
+    "compiles_by_function", "cached_jit", "clear_jit_registry", "config_signature",
     "xla_flags_string", "merge_xla_flags", "apply_to_env", "child_env",
     "force_host_device_count", "ensure_host_device_count",
     "resolve_xla_settings", "set_xla_override", "promote_xla_settings",
@@ -124,35 +125,32 @@ def enable_persistent_cache() -> Optional[Path]:
 # Process-local jit registry (front b, in-process half)
 # =============================================================================
 _JIT_LOCK = threading.Lock()
-_JIT_REGISTRY: Dict[Any, "_CachedJit"] = {}
-_COUNTERS = {"hits": 0, "misses": 0, "compile_seconds": 0.0}
+_JIT_REGISTRY: Dict[Any, Callable] = {}
+_COUNTERS = {"hits": 0, "misses": 0}
+# JAX's own event around every executable it builds or loads from the
+# persistent cache, at any shape; it carries the jitted function's name.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# (fun_name, end on the perf_counter clock, seconds) of each such event
+_COMPILES: List[Tuple[str, float, float]] = []
+_LISTENING = False
 
 
-class _CachedJit:
-    """A jitted callable that attributes its first-call wall time (trace +
-    compile + first execute — the startup cost the persistent cache attacks)
-    to the registry's ``compile_seconds`` counter."""
+def _on_duration(event: str, seconds: float, **kw: Any) -> None:
+    if event == COMPILE_EVENT:
+        with _JIT_LOCK:
+            _COMPILES.append((str(kw.get("fun_name", "?")), time.perf_counter(), float(seconds)))
 
-    __slots__ = ("_jitted", "_first", "registry_key")
 
-    def __init__(self, jitted: Any, registry_key: Any):
-        self._jitted = jitted
-        self._first = True
-        self.registry_key = registry_key
+def _listen_for_compiles() -> None:
+    """Register the compile listener once per process."""
+    global _LISTENING
+    with _JIT_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    import jax.monitoring
 
-    def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        if self._first:
-            t0 = time.perf_counter()
-            out = self._jitted(*args, **kwargs)
-            dt = time.perf_counter() - t0
-            with _JIT_LOCK:
-                _COUNTERS["compile_seconds"] += dt
-            self._first = False
-            return out
-        return self._jitted(*args, **kwargs)
-
-    def __getattr__(self, name: str) -> Any:  # .lower(), .trace(), ...
-        return getattr(self._jitted, name)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def config_signature(obj: Any) -> str:
@@ -181,7 +179,8 @@ def cached_jit(fn: Callable, *, key: str, context: Hashable = None,
     of it — jax retraces per shape under one callable as usual.
 
     The first use also wires the persistent compilation cache, so the miss
-    path's XLA compile is itself served from disk on repeat runs.
+    path's XLA compile is itself served from disk on repeat runs, and starts
+    counting compiles (:func:`cache_counters`, :func:`compiles_by_function`).
 
     ``donate_argnums`` and ``persistent=True`` are mutually exclusive: XLA's
     CPU runtime under an earlier jaxlib mis-handled ``input_output_aliases``
@@ -196,6 +195,7 @@ def cached_jit(fn: Callable, *, key: str, context: Hashable = None,
             f"cached_jit({key!r}): donate_argnums with persistent=True would "
             "deserialize a donating executable into a use-after-free; pass "
             "persistent=False to donate, or drop donation to persist")
+    _listen_for_compiles()
     registry_key = (key, context, tuple(static_argnums), tuple(donate_argnums))
     with _JIT_LOCK:
         entry = _JIT_REGISTRY.get(registry_key)
@@ -207,9 +207,8 @@ def cached_jit(fn: Callable, *, key: str, context: Hashable = None,
         enable_persistent_cache()
     import jax  # lazy: keep this module importable pre-backend-init
 
-    jitted = jax.jit(fn, static_argnums=static_argnums or None,
-                     donate_argnums=donate_argnums or None)
-    entry = _CachedJit(jitted, registry_key)
+    entry = jax.jit(fn, static_argnums=static_argnums or None,
+                    donate_argnums=donate_argnums or None)
     with _JIT_LOCK:
         # Two threads may race to compile the same key; first write wins so
         # every caller shares one trace cache.
@@ -218,17 +217,36 @@ def cached_jit(fn: Callable, *, key: str, context: Hashable = None,
 
 
 def cache_counters() -> Dict[str, float]:
-    """Snapshot of the registry telemetry: hits, misses, compile_seconds and
-    the number of live compiled entries (exported via ``core.telemetry``)."""
+    """Snapshot of the registry telemetry (exported via ``core.telemetry``):
+    hits, misses, the number of live compiled entries, and the compiles JAX
+    reported since the first :func:`cached_jit` with their summed seconds."""
     with _JIT_LOCK:
-        return {**_COUNTERS, "entries": float(len(_JIT_REGISTRY))}
+        return {**_COUNTERS, "entries": float(len(_JIT_REGISTRY)),
+                "compiles": float(len(_COMPILES)),
+                "compile_seconds": float(sum(c[2] for c in _COMPILES))}
+
+
+def compiles_by_function(since: Optional[float] = None,
+                         until: Optional[float] = None) -> Dict[str, int]:
+    """Compiles per jitted function name (``jit(<name>)``), optionally only
+    those that ended inside ``[since, until]`` on the ``time.perf_counter``
+    clock."""
+    lo = float("-inf") if since is None else since
+    hi = float("inf") if until is None else until
+    out: Dict[str, int] = {}
+    with _JIT_LOCK:
+        for name, end, _ in _COMPILES:
+            if lo <= end <= hi:
+                out[name] = out.get(name, 0) + 1
+    return out
 
 
 def clear_jit_registry() -> None:
     """Drop memoized callables + zero the counters (tests)."""
     with _JIT_LOCK:
         _JIT_REGISTRY.clear()
-        _COUNTERS.update(hits=0, misses=0, compile_seconds=0.0)
+        _COUNTERS.update(hits=0, misses=0)
+        _COMPILES.clear()
 
 
 # =============================================================================

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import os
 import re
-import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from .channel import MlosChannel
@@ -26,14 +25,26 @@ from .codegen import pack_telemetry
 from .registry import ComponentMeta
 
 __all__ = ["os_counters", "hlo_counters", "collective_bytes", "compile_cache_counters",
-           "TelemetryEmitter", "Stopwatch"]
+           "TelemetryEmitter", "span"]
+
+
+def span(name: str, **ids: Any) -> Any:
+    """Open a span on the profiler's host timeline: ``with span("serve.step",
+    sync=3): ...``.  A ``jax.profiler.TraceAnnotation``, so the span lands in
+    the same trace as the device's operations, on the same clock, with
+    ``ids`` as its args.  The one way the program opens a span; with no
+    profiler running it costs about a microsecond."""
+    from jax.profiler import TraceAnnotation  # lazy: importable without a backend
+
+    return TraceAnnotation(name, **ids)
 
 
 def compile_cache_counters() -> Dict[str, float]:
     """Jit-registry telemetry (``core.compilecache``): hits, misses, live
-    entries, and the compile-seconds the process has paid — the counters the
-    persistent compilation cache is meant to drive toward zero.  Lazy import:
-    telemetry stays importable before the backend initializes."""
+    entries, and the compiles and compile-seconds the process has paid —
+    the counters the persistent compilation cache is meant to drive toward
+    zero.  Lazy import: telemetry stays importable before the backend
+    initializes."""
     from .compilecache import cache_counters
 
     return cache_counters()
@@ -195,17 +206,6 @@ def hlo_counters(compiled: Any, lowered_text: Optional[str] = None) -> Dict[str,
     for k, v in coll.items():
         out[f"collective_bytes[{k}]"] = float(v)
     return out
-
-
-class Stopwatch:
-    """Context manager timing a critical section (the app metric of the paper)."""
-
-    def __enter__(self) -> "Stopwatch":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        self.elapsed_s = time.perf_counter() - self.t0
 
 
 class TelemetryEmitter:

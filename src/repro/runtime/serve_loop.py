@@ -50,6 +50,7 @@ import numpy as np
 from ..core.compilecache import cached_jit, config_signature
 from ..core.configstore import bucket_pow2
 from ..core.registry import MetricSpec, tunable_component
+from ..core.telemetry import span
 from ..core.tunable import Int
 from ..models import model as M
 from ..models.config import ModelConfig
@@ -269,17 +270,20 @@ class BatchedServer:
         return admitted
 
     def _prefill_into(self, slot: int, r: _Request, width: int) -> None:
-        if self._caches is None:
-            self._caches = M.init_cache(self.cfg, self.max_batch, self.capacity,
-                                        self._enc_len)
-        toks = self._pad_prompts([r], 1, width)
-        logits, small, _ = self._prefill_fn(self.params, jnp.asarray(toks),
-                                            self._modal(1))
-        # first token stays on device: it flows into the decode stream and
-        # reaches the host with the next batched sync, not here
-        self._caches, self._tok, self._pos, self._done = self._install(
-            self._caches, small, jnp.asarray(slot, jnp.int32), self._tok,
-            self._pos, self._done, logits, jnp.asarray(width, jnp.int32))
+        wait_us = int((time.perf_counter() - r.submitted) * 1e6)
+        with span("serve.prefill", rid=r.rid, n_prompt=len(r.prompt), width=width,
+                  wait_us=wait_us):
+            if self._caches is None:
+                self._caches = M.init_cache(self.cfg, self.max_batch, self.capacity,
+                                            self._enc_len)
+            toks = self._pad_prompts([r], 1, width)
+            logits, small, _ = self._prefill_fn(self.params, jnp.asarray(toks),
+                                                self._modal(1))
+            # first token stays on device: it flows into the decode stream and
+            # reaches the host with the next batched sync, not here
+            self._caches, self._tok, self._pos, self._done = self._install(
+                self._caches, small, jnp.asarray(slot, jnp.int32), self._tok,
+                self._pos, self._done, logits, jnp.asarray(width, jnp.int32))
         r.slot = slot
         r.eff_budget = self._eff_budget(r, width)
         self._slot_req[slot] = r
@@ -333,29 +337,40 @@ class BatchedServer:
     def step(self) -> List[_Request]:
         """One scheduler step: admit into free slots, run ``sync_interval``
         decode steps on device, then one host sync.  Returns the requests
-        that completed at this sync."""
-        self._admit()
-        if not self._n_live():
-            return []
-        emitted = []
-        for _ in range(self.sync_interval):
-            # emit-input scheme: each step CONSUMES self._tok (writes its
-            # KV at pos and predicts the next), so the stream of step
-            # inputs is exactly the generated-token stream — the prefill's
-            # first token included — with zero extra host reads.
-            emitted.append(self._tok)
-            self._tok, self._caches, self._pos, self._done = self._decode(
-                self.params, self._tok, self._caches, self._pos, self._done)
-            self.decode_steps += 1
-            self._run_steps += 1
-        finished = self._sync(emitted)
-        self._emit_rolling()
-        return finished
+        that completed at this sync.
+
+        Each phase is a span on the profiler's host timeline (``serve.step``
+        around ``serve.admit`` ⊃ ``serve.prefill`` per admitted request,
+        ``serve.decode``, ``serve.sync`` ⊃ ``serve.fetch``, and
+        ``serve.telemetry``); none wraps per-token work."""
+        with span("serve.step", sync=self.decode_syncs):
+            with span("serve.admit", queued=len(self.queue)):
+                self._admit()
+            if not self._n_live():
+                return []
+            emitted = []
+            with span("serve.decode", n=self.sync_interval):
+                for _ in range(self.sync_interval):
+                    # emit-input scheme: each step CONSUMES self._tok (writes its
+                    # KV at pos and predicts the next), so the stream of step
+                    # inputs is exactly the generated-token stream — the prefill's
+                    # first token included — with zero extra host reads.
+                    emitted.append(self._tok)
+                    self._tok, self._caches, self._pos, self._done = self._decode(
+                        self.params, self._tok, self._caches, self._pos, self._done)
+                    self.decode_steps += 1
+                    self._run_steps += 1
+            with span("serve.sync"):
+                finished = self._sync(emitted)
+            with span("serve.telemetry"):
+                self._emit_rolling()
+            return finished
 
     def _sync(self, emitted: List[jax.Array]) -> List[_Request]:
         self.decode_syncs += 1
         self._run_syncs += 1
-        fetched = _host_fetch((emitted, self._done))
+        with span("serve.fetch"):
+            fetched = _host_fetch((emitted, self._done))
         toks_h, done_h = np.stack(fetched[0]), fetched[1]   # stack on host
         now = time.perf_counter()
         finished: List[_Request] = []

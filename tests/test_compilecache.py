@@ -17,6 +17,7 @@ from repro import compat
 from repro.core import compilecache, configstore
 from repro.core.compilecache import (XLA_RUNTIME_SPACE, cache_counters,
                                      cached_jit, child_env, clear_jit_registry,
+                                     compiles_by_function,
                                      config_signature, ensure_host_device_count,
                                      force_host_device_count, merge_xla_flags,
                                      promote_xla_settings, resolve_xla_settings,
@@ -112,6 +113,47 @@ def test_cached_jit_no_retrace_across_reconstruction(registry):
     np.testing.assert_allclose(np.asarray(g(x)), 2 * x)
     assert traces == ["first"]  # second build never traced
     assert cache_counters()["compile_seconds"] > 0
+
+
+def test_cached_jit_counts_a_compile_per_shape(registry):
+    """JAX's compile event counts every executable built under one key, at
+    each shape, under the jitted function's name; a call at a shape already
+    built counts nothing."""
+    def tiny_double(x):
+        return x * 2
+
+    f = cached_jit(tiny_double, key="t.shapes", persistent=False)
+    assert f(np.ones((3,), np.float32)).shape == (3,)
+    assert f(np.ones((5,), np.float32)).shape == (5,)
+    f(np.ones((3,), np.float32))
+    c = cache_counters()
+    assert c["compiles"] == 2 and c["compile_seconds"] > 0
+    assert compiles_by_function() == {"jit(tiny_double)": 2}
+
+
+def test_compiles_by_function_reads_a_window(registry):
+    import time
+
+    def tiny_window(x):
+        return x + 3
+
+    f = cached_jit(tiny_window, key="t.window", persistent=False)
+    f(np.ones((2,), np.float32))
+    t0 = time.perf_counter()
+    f(np.ones((2,), np.float32))            # already built: no compile
+    assert compiles_by_function(since=t0) == {}
+    f(np.ones((7,), np.float32))
+    t1 = time.perf_counter()
+    assert compiles_by_function(since=t0, until=t1) == {"jit(tiny_window)": 1}
+    assert compiles_by_function(until=t0) == {"jit(tiny_window)": 1}
+    clear_jit_registry()
+    assert compiles_by_function() == {} and cache_counters()["compiles"] == 0
+
+
+def test_cached_jit_returns_the_jitted_callable(registry):
+    f = cached_jit(lambda x: x - 1, key="t.plain", persistent=False)
+    assert isinstance(f, type(jax.jit(lambda x: x)))
+    assert f.lower(np.ones((2,), np.float32)).compile() is not None
 
 
 def test_cached_jit_donation_excludes_persistence(registry):
