@@ -6,7 +6,8 @@ MLOS-tunable impl/block knobs apply uniformly to every architecture.
 Conventions:
   * activations x: (B, S, d_model); q/k/v: (B, S, H|K, hd)
   * KV cache per layer: dict(k=(B, C, K, hd), v=(B, C, K, hd)); capacity
-    C = cfg.cache_len(context) — a ring buffer when C == window.
+    C = cfg.cache_len(context) — a ring buffer when C == window.  Decode
+    may instead take the stacked leaves (L, B, C, K, hd) and a layer index.
   * ``pos`` is a scalar int32 = number of tokens already consumed.
 """
 from __future__ import annotations
@@ -142,6 +143,7 @@ def apply_attn_decode(
     cfg: ModelConfig,
     *,
     cross: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token attention against (and update of) a KV cache.
 
@@ -151,14 +153,24 @@ def apply_attn_decode(
     batching: each slot carries its own position, rope phase and validity
     horizon).  Cross-attention caches are static (pre-filled from the
     encoder/modal source) and not updated.
+
+    With ``layer``, the cache leaves are the whole layer stack
+    ``(L, B, C, K, hd)``: the token is written into layer ``layer`` of the
+    stack itself and attention reads that layer where it lies, so no copy
+    of the layer's cache is made (the stack is the scan's donated carry).
     """
-    c = cache["k"].shape[1]
+    c = cache["k"].shape[-3]
+
+    def read(t: jax.Array) -> jax.Array:
+        return t if layer is None else jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+
     if cross:
         q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
         if "bq" in params:
             q = q + params["bq"]
         q = constrain(q, ("batch", None, None, None))
-        y = attn_ops.decode_attention(q, cache["k"], cache["v"], jnp.asarray(c - 1, jnp.int32))
+        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]),
+                                      jnp.asarray(c - 1, jnp.int32))
     else:
         per_row = pos.ndim == 1
         q, k, v = _project_qkv(
@@ -174,18 +186,18 @@ def apply_attn_decode(
         k = constrain(k, ("batch", None, None, None))
         v = constrain(v, ("batch", None, None, None))
         slot = (pos % c).astype(jnp.int32)
+        lead = () if layer is None else (layer,)
         if per_row:
             rows = jnp.arange(x.shape[0])
-            cache = dict(
-                k=cache["k"].at[rows, slot].set(k[:, 0].astype(cache["k"].dtype)),
-                v=cache["v"].at[rows, slot].set(v[:, 0].astype(cache["v"].dtype)),
-            )
+
+            def write(t: jax.Array, u: jax.Array) -> jax.Array:
+                return t.at[(*lead, rows, slot)].set(u[:, 0].astype(t.dtype))
         else:
-            cache = dict(
-                k=jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), slot, axis=1),
-                v=jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), slot, axis=1),
-            )
-        y = attn_ops.decode_attention(q, cache["k"], cache["v"], pos, window=cfg.window)
+            def write(t: jax.Array, u: jax.Array) -> jax.Array:
+                u = u.astype(t.dtype).reshape((1,) * len(lead) + u.shape)
+                return jax.lax.dynamic_update_slice(t, u, (*lead, 0, slot, 0, 0))
+        cache = dict(k=write(cache["k"], k), v=write(cache["v"], v))
+        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]), pos, window=cfg.window)
     y = jnp.einsum("bshe,hed->bsd", y, params["wo"])
     if "bo" in params:
         y = y + params["bo"]

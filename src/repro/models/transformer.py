@@ -294,42 +294,18 @@ def decode_stack(
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One-token pass over the layer stack.
 
-    The cache stack rides in the scan CARRY and is updated in place with
-    dynamic_update_slice — passing caches as scan xs→ys double-buffers the
-    entire KV cache (measured +6.4 GB/device on deepseek-67B decode_32k).
+    The cache stack rides in the scan CARRY (passing caches as scan xs→ys
+    double-buffers the entire KV cache: measured +6.4 GB/device on
+    deepseek-67B decode_32k) and never leaves it.  Each layer's attention
+    writes the new token's K/V into the stack at ``[i, row, pos % C]`` and
+    reads layer ``i`` by a dynamic index that feeds only the attention
+    einsums; cross-attention caches are read the same way and not written.
+    Only the small SSM state is sliced out and put back whole.  The vlm
+    group path still slices each group's caches out and puts them back.
     """
     kind = cfg.family if kind == "auto" else kind
     scan = stack_settings.settings_for(
         stack_workload(kind, x.shape[0], x.shape[1], cfg.n_layers))["scan_layers"]
-
-    def body(xx, lp_cache):
-        lp, cache = lp_cache
-        new_cache: Dict[str, Any] = {}
-        if kind in ("dense", "moe", "hybrid", "decoder"):
-            xn = apply_norm(lp["ln1"], xx, cfg)
-            h, kv = apply_attn_decode(lp["attn"], xn, {"k": cache["k"], "v": cache["v"]}, pos, cfg)
-            new_cache.update(kv)
-            if kind == "hybrid":
-                s_out, sstate = apply_ssm_decode(lp["ssm"], xn, cache["ssm"], cfg)
-                h = (h + s_out) / 2.0
-                new_cache["ssm"] = sstate
-            xx = xx + h
-        if kind == "ssm":
-            y, sstate = apply_ssm_decode(lp["ssm"], apply_norm(lp["ln1"], xx, cfg), cache["ssm"], cfg)
-            new_cache["ssm"] = sstate
-            xx = xx + y
-        if kind == "decoder":
-            xn = apply_norm(lp["lnx"], xx, cfg)
-            h, _ = apply_attn_decode(lp["xattn"], xn, {"k": cache["xk"], "v": cache["xv"]},
-                                     pos, cfg, cross=True)
-            new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
-            xx = xx + h
-        if kind in ("dense", "hybrid", "decoder"):
-            xx = xx + apply_mlp(lp["mlp"], apply_norm(lp["ln2"], xx, cfg), cfg)
-        if kind == "moe":
-            y, _ = apply_moe(lp["moe"], apply_norm(lp["ln2"], xx, cfg), cfg)
-            xx = xx + y
-        return xx, new_cache
 
     def _at(tree, i):
         return jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False), tree)
@@ -339,7 +315,43 @@ def decode_stack(
             lambda t, u: jax.lax.dynamic_update_index_in_dim(t, u.astype(t.dtype), i, 0),
             tree, sub)
 
+    def ssm_step(lp, xn, cache, i):
+        # the SSM state is KBs a layer: slicing it out and back costs nothing
+        state = cache["ssm"] if i is None else _at(cache["ssm"], i)
+        y, state = apply_ssm_decode(lp["ssm"], xn, state, cfg)
+        cache["ssm"] = state if i is None else _put(cache["ssm"], state, i)
+        return y
+
+    def body(xx, lp, cache, i=None):
+        """One layer.  With ``i``, ``cache`` is the whole stack and layer
+        ``i`` is updated inside it; without, it is the layer's own cache."""
+        cache = dict(cache)
+        if kind in ("dense", "moe", "hybrid", "decoder"):
+            xn = apply_norm(lp["ln1"], xx, cfg)
+            h, kv = apply_attn_decode(lp["attn"], xn, {"k": cache["k"], "v": cache["v"]},
+                                      pos, cfg, layer=i)
+            cache.update(kv)
+            if kind == "hybrid":
+                h = (h + ssm_step(lp, xn, cache, i)) / 2.0
+            xx = xx + h
+        if kind == "ssm":
+            xx = xx + ssm_step(lp, apply_norm(lp["ln1"], xx, cfg), cache, i)
+        if kind == "decoder":
+            xn = apply_norm(lp["lnx"], xx, cfg)
+            h, _ = apply_attn_decode(lp["xattn"], xn, {"k": cache["xk"], "v": cache["xv"]},
+                                     pos, cfg, cross=True, layer=i)
+            xx = xx + h
+        if kind in ("dense", "hybrid", "decoder"):
+            xx = xx + apply_mlp(lp["mlp"], apply_norm(lp["ln2"], xx, cfg), cfg)
+        if kind == "moe":
+            y, _ = apply_moe(lp["moe"], apply_norm(lp["ln2"], xx, cfg), cfg)
+            xx = xx + y
+        return xx, cache
+
     if kind == "vlm":
+        # Still the copying form: each group's caches are sliced out of the
+        # stack and put back whole, and so is each inner layer's.  No
+        # benchmark cell serves a vlm yet.
         def group(carry, lp_i):
             lp, i = lp_i
             xx, cstack = carry
@@ -352,7 +364,7 @@ def decode_stack(
             def inner(carry2, lp_j):
                 lp2, j = lp_j
                 xx2, inner_stack = carry2
-                xx2, new_c = body(xx2, (lp2, _at(inner_stack, j)))
+                xx2, new_c = body(xx2, lp2, _at(inner_stack, j))
                 return (xx2, _put(inner_stack, new_c, j)), None
 
             (xx, inner_stack), _ = _maybe_scan(
@@ -375,8 +387,7 @@ def decode_stack(
     def layer(carry, lp_i):
         lp, i = lp_i
         xx, cstack = carry
-        xx, new_cache = body(xx, (lp, _at(cstack, i)))
-        return (xx, _put(cstack, new_cache, i)), None
+        return body(xx, lp, cstack, i), None
 
     (x, caches), _ = _maybe_scan(layer, (x, caches),
                                  (stacked, jnp.arange(cfg.n_layers)), cfg.n_layers,

@@ -35,3 +35,24 @@ def wait_until(predicate, *, timeout_s: float = 30.0, tick=None,
         else:
             time.sleep(sleep_s)
     return True
+
+
+def shaped_instructions(hlo_text: str, dims, *, in_fusions: bool = True) -> list:
+    """Non-bitcast instructions of an optimised HLO module whose result has
+    shape ``dims``, as ``(name, opcode)``.  With ``in_fusions=False`` only
+    those outside fusion bodies count: the buffers the program materialises.
+    """
+    import re
+
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo_text))
+    shape = r"\w+\[" + ",".join(str(d) for d in dims) + r"\]"
+    out, comp = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = " + shape + r"\S* ([\w\-]+)\(", line)
+        if m and m.group(2) != "bitcast" and (in_fusions or comp not in fused):
+            out.append((m.group(1), m.group(2)))
+    return out

@@ -14,6 +14,7 @@ compiles (an entry compiled for a described chip cannot be read back here).
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import shaped_instructions
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
@@ -108,12 +109,29 @@ def test_serve_prefill_compiles_and_fits(one_chip, olmo_server):
     assert _bytes(c) < HBM_BYTES
 
 
-def test_serve_fused_decode_compiles_and_fits(one_chip, olmo_server):
+@pytest.fixture(scope="module")
+def olmo_decode(one_chip, olmo_server):
+    """The engine's donated fused decode step, compiled for one chip."""
     srv, params = olmo_server
     cfg, mb = srv.cfg, srv.max_batch
     caches = jax.eval_shape(lambda: M.init_cache(cfg, mb, srv.capacity, srv._enc_len))
     caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), caches)
     i32 = _sds((mb,), jnp.int32, one_chip)
     done = _sds((mb,), jnp.bool_, one_chip)
-    c = srv._decode.lower(params, i32, caches, i32, done).compile()
-    assert _bytes(c) < HBM_BYTES
+    return srv._decode.lower(params, i32, caches, i32, done).compile()
+
+
+def test_serve_fused_decode_compiles_and_fits(olmo_decode):
+    assert _bytes(olmo_decode) < HBM_BYTES
+
+
+def test_serve_fused_decode_keeps_kv_in_the_stack(olmo_server, olmo_decode):
+    """No buffer shaped like one layer's K or V: the token is written into
+    the stacked cache in place and attention reads its layer where it lies
+    (the copying form materialised four such buffers per layer)."""
+    srv, _ = olmo_server
+    layer = (srv.max_batch, srv.capacity, srv.cfg.n_kv_heads, srv.cfg.hd)
+    text = olmo_decode.as_text()
+    assert shaped_instructions(text, layer, in_fusions=False) == []
+    layer_bytes = 2 * srv.max_batch * srv.capacity * srv.cfg.n_kv_heads * srv.cfg.hd  # bf16
+    assert olmo_decode.memory_analysis().temp_size_in_bytes < layer_bytes
