@@ -93,5 +93,6 @@ def flash_attention(
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
-    return ref.decode_attention(q, k_cache, v_cache, pos, window=window)
+def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0, kv_head_major: bool = False):
+    return ref.decode_attention(q, k_cache, v_cache, pos, window=window,
+                                kv_head_major=kv_head_major)

@@ -157,11 +157,12 @@ def unrolled_attention(
 
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, pos: jax.Array, *,
-    window: int = 0, scale: Optional[float] = None,
+    window: int = 0, scale: Optional[float] = None, kv_head_major: bool = False,
 ) -> jax.Array:
     """Single-token attention against a (possibly ring-buffered) KV cache.
 
-    q: (B, 1, H, D); caches: (B, C, K, D) where C = cache capacity.
+    q: (B, 1, H, D); caches: (B, C, K, D) where C = cache capacity, or
+    (B, K, C, D) with ``kv_head_major``.
     ``pos`` — int32, scalar or per-row ``(B,)``: number of tokens already in
     context (0-based index of the current token).  A vector ``pos`` gives
     every batch row its own validity horizon — the continuous-batching case
@@ -169,10 +170,15 @@ def decode_attention(
     caches (C == window) the cache is a ring buffer indexed ``t % C``;
     validity is derived from ``pos``.
     """
-    b, c, n_kv, d = k_cache.shape
+    if kv_head_major:
+        b, n_kv, c, d = k_cache.shape
+        kv = "bksd"
+    else:
+        b, c, n_kv, d = k_cache.shape
+        kv = "bskd"
     scale = scale or 1.0 / math.sqrt(q.shape[-1])
     qg = _group_q(q, n_kv)  # (b,1,k,g,d)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
+    s = jnp.einsum(f"bqkgd,{kv}->bkgqs", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale
     slot = jnp.arange(c)
     # (1,1) for scalar pos, (B,1) per-row: one mask expression serves both.
@@ -183,6 +189,12 @@ def decode_attention(
         valid = jnp.where(pos_r >= c, jnp.ones_like(valid), valid)
     s = jnp.where(valid[:, None, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v_cache.dtype), v_cache,
-                   preferred_element_type=jnp.float32)
+    p = p.astype(v_cache.dtype)
+    if kv_head_major:
+        # batch dims leading in the output: the form XLA's CPU backend also
+        # runs in bfloat16 (q is one token, so the move costs nothing)
+        o = jnp.moveaxis(jnp.einsum("bkgqs,bksd->bkgqd", p, v_cache,
+                                    preferred_element_type=jnp.float32), 3, 1)
+    else:
+        o = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache, preferred_element_type=jnp.float32)
     return o.reshape(b, 1, q.shape[2], d).astype(q.dtype)

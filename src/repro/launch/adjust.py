@@ -30,6 +30,7 @@ from jax.sharding import Mesh
 
 from ..core.telemetry import hlo_counters
 from ..kernels.flash_attention import ops as attn_ops
+from ..models.attention import attn_cache_spec, kv_head_major
 from ..models.config import ModelConfig
 from ..models.layers import P, dtype_of
 from ..parallel import sharding as shd
@@ -70,13 +71,14 @@ def attention_adjustment(cfg: ModelConfig, shape: Shape, mesh: Mesh,
     else:
         sq = skv = shape.seq_len
 
-    def struct(s, logical):
-        return jax.ShapeDtypeStruct(s, dt, sharding=shd.sharding_for(
-            P(s, logical), rules, mesh))
+    def struct(p):
+        return jax.ShapeDtypeStruct(p.shape, dt, sharding=shd.sharding_for(p, rules, mesh))
 
-    q = struct((b, sq, cfg.n_heads, cfg.hd), ("batch", None, "heads", None))
-    k = struct((b, skv, cfg.n_kv_heads, cfg.hd),
-               ("batch", "cache_seq" if shape.kind == "decode" else None, "kv_heads", None))
+    q = struct(P((b, sq, cfg.n_heads, cfg.hd), ("batch", None, "heads", None)))
+    if shape.kind == "decode":   # the KV cache as decode holds it
+        k = struct(attn_cache_spec(cfg, b, shape.seq_len)["k"])
+    else:
+        k = struct(P((b, skv, cfg.n_kv_heads, cfg.hd), ("batch", None, "kv_heads", None)))
     v = k
 
     train = shape.kind == "train"
@@ -85,7 +87,7 @@ def attention_adjustment(cfg: ModelConfig, shape: Shape, mesh: Mesh,
         impl = "unrolled" if shape.kind != "decode" else None
         if shape.kind == "decode":
             out = attn_ops.decode_attention(q, k, v, jnp.asarray(skv - 1, jnp.int32),
-                                            window=cfg.window)
+                                            window=cfg.window, kv_head_major=kv_head_major(cfg))
         else:
             out = attn_ops.flash_attention(q, k, v, causal=True, window=cfg.window,
                                            impl="unrolled")

@@ -6,8 +6,10 @@ MLOS-tunable impl/block knobs apply uniformly to every architecture.
 Conventions:
   * activations x: (B, S, d_model); q/k/v: (B, S, H|K, hd)
   * KV cache per layer: dict(k=(B, C, K, hd), v=(B, C, K, hd)); capacity
-    C = cfg.cache_len(context) — a ring buffer when C == window.  Decode
-    may instead take the stacked leaves (L, B, C, K, hd) and a layer index.
+    C = cfg.cache_len(context) — a ring buffer when C == window.  Grouped KV
+    heads (K < H) are stored head-major instead, (B, K, C, hd)
+    (:func:`kv_head_major`).  Decode may instead take the stacked leaves
+    (L, B, ...) and a layer index.
   * ``pos`` is a scalar int32 = number of tokens already consumed.
 """
 from __future__ import annotations
@@ -23,7 +25,8 @@ from ..parallel.sharding import active_rules, constrain, spec_for
 from .config import ModelConfig
 from .layers import P, rope
 
-__all__ = ["attn_params", "cross_attn_params", "attn_cache_spec", "apply_attn", "apply_attn_decode"]
+__all__ = ["attn_params", "cross_attn_params", "attn_cache_spec", "kv_head_major",
+           "cache_layout", "apply_attn", "apply_attn_decode"]
 
 
 def attn_params(cfg: ModelConfig, cross: bool = False) -> Dict[str, P]:
@@ -50,11 +53,35 @@ def cross_attn_params(cfg: ModelConfig) -> Dict[str, P]:
     return attn_params(cfg, cross=True)
 
 
+def kv_head_major(cfg: ModelConfig) -> bool:
+    """Whether the KV cache is stored (B, K, C, hd) rather than (B, C, K, hd).
+
+    Grouped heads make decode's two attention products batched dots over
+    (B, K).  XLA's TPU compiler feeds such a dot a layer of the stacked
+    cache where it lies only when K is ahead of C; in the (B, C, K, hd)
+    order it first copies the layer's K and V out of the stack, every step.
+    One query per KV head (olmo-1b) compiles to multiply-reduces that read
+    the sequence-major layer in place, and keeps it: stored head-major, its
+    products become dots, which changes its TPU program, and XLA's CPU
+    backend then copies each layer's bfloat16 V to float32 every step
+    (``tests/test_decode_inplace.py`` finds two such buffers)."""
+    return cfg.n_kv_heads < cfg.n_heads
+
+
+def cache_layout(kv: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """K or V of a sequence, (B, S, K, hd), in the cache's order."""
+    return jnp.swapaxes(kv, 1, 2) if kv_head_major(cfg) else kv
+
+
 def attn_cache_spec(cfg: ModelConfig, batch: int, context: int) -> Dict[str, P]:
     """Per-layer KV-cache leaf specs (stacked over layers by the caller)."""
     c = cfg.cache_len(context)
-    shape = (batch, c, cfg.n_kv_heads, cfg.hd)
-    logical = ("batch", "cache_seq", "kv_heads", "head_dim")
+    if kv_head_major(cfg):
+        shape = (batch, cfg.n_kv_heads, c, cfg.hd)
+        logical = ("batch", "kv_heads", "cache_seq", "head_dim")
+    else:
+        shape = (batch, c, cfg.n_kv_heads, cfg.hd)
+        logical = ("batch", "cache_seq", "kv_heads", "head_dim")
     return {"k": P(shape, logical, "zeros"), "v": P(shape, logical, "zeros")}
 
 
@@ -155,11 +182,12 @@ def apply_attn_decode(
     encoder/modal source) and not updated.
 
     With ``layer``, the cache leaves are the whole layer stack
-    ``(L, B, C, K, hd)``: the token is written into layer ``layer`` of the
-    stack itself and attention reads that layer where it lies, so no copy
-    of the layer's cache is made (the stack is the scan's donated carry).
+    ``(L, B, ...)``: the token is written into layer ``layer`` of the stack
+    itself and attention reads that layer where it lies, so no copy of the
+    layer's cache is made (the stack is the scan's donated carry).
     """
-    c = cache["k"].shape[-3]
+    head_major = kv_head_major(cfg)
+    c = cache["k"].shape[-2 if head_major else -3]
 
     def read(t: jax.Array) -> jax.Array:
         return t if layer is None else jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
@@ -170,7 +198,7 @@ def apply_attn_decode(
             q = q + params["bq"]
         q = constrain(q, ("batch", None, None, None))
         y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]),
-                                      jnp.asarray(c - 1, jnp.int32))
+                                      jnp.asarray(c - 1, jnp.int32), kv_head_major=head_major)
     else:
         per_row = pos.ndim == 1
         q, k, v = _project_qkv(
@@ -189,15 +217,23 @@ def apply_attn_decode(
         lead = () if layer is None else (layer,)
         if per_row:
             rows = jnp.arange(x.shape[0])
+            # head-major: every index broadcast to (B, K), so the scatter's
+            # indexed dims are adjacent and it stays in place (a slice
+            # between them makes XLA copy the whole stack)
+            at = ((rows[:, None], jnp.arange(cfg.n_kv_heads)[None, :], slot[:, None])
+                  if head_major else (rows, slot))
 
             def write(t: jax.Array, u: jax.Array) -> jax.Array:
-                return t.at[(*lead, rows, slot)].set(u[:, 0].astype(t.dtype))
+                return t.at[(*lead, *at)].set(u[:, 0].astype(t.dtype))
         else:
             def write(t: jax.Array, u: jax.Array) -> jax.Array:
-                u = u.astype(t.dtype).reshape((1,) * len(lead) + u.shape)
-                return jax.lax.dynamic_update_slice(t, u, (*lead, 0, slot, 0, 0))
+                u = cache_layout(u, cfg).astype(t.dtype)
+                u = u.reshape((1,) * len(lead) + u.shape)
+                return jax.lax.dynamic_update_slice(t, u, (*lead, 0, 0, slot, 0) if head_major
+                                                    else (*lead, 0, slot, 0, 0))
         cache = dict(k=write(cache["k"], k), v=write(cache["v"], v))
-        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]), pos, window=cfg.window)
+        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]), pos,
+                                      window=cfg.window, kv_head_major=head_major)
     y = jnp.einsum("bshe,hed->bsd", y, params["wo"])
     if "bo" in params:
         y = y + params["bo"]

@@ -24,7 +24,8 @@ from ..core.configstore import bucket_pow2
 from ..core.registry import MetricSpec, tunable_component
 from ..core.tunable import Categorical, Int
 from ..parallel.sharding import constrain
-from .attention import apply_attn, apply_attn_decode, attn_params, cross_attn_params
+from .attention import (apply_attn, apply_attn_decode, attn_params, cache_layout,
+                        cross_attn_params)
 from .config import ModelConfig
 from .layers import P, apply_mlp, apply_norm, mlp_params, norm_params
 from .moe import apply_moe, moe_params
@@ -231,7 +232,7 @@ def prefill_stack(
         if kind in ("dense", "moe", "hybrid", "decoder"):
             xn = apply_norm(lp["ln1"], xx, cfg)
             h, (k, v) = apply_attn(lp["attn"], xn, cfg, causal=True, return_kv=True)
-            cache["k"], cache["v"] = pad_kv(k), pad_kv(v)
+            cache["k"], cache["v"] = cache_layout(pad_kv(k), cfg), cache_layout(pad_kv(v), cfg)
             if kind == "hybrid":
                 s_out, sstate = apply_ssm(lp["ssm"], xn, cfg, return_state=True)
                 h = (h + s_out) / 2.0
@@ -244,7 +245,7 @@ def prefill_stack(
         if kind == "decoder":
             xn = apply_norm(lp["lnx"], xx, cfg)
             h, (xk, xv) = apply_attn(lp["xattn"], xn, cfg, xkv=xattn_src, return_kv=True)
-            cache["xk"], cache["xv"] = xk, xv
+            cache["xk"], cache["xv"] = cache_layout(xk, cfg), cache_layout(xv, cfg)
             xx = _res(xx + h)
         if kind in ("dense", "hybrid", "decoder"):
             xx = _res(xx + apply_mlp(lp["mlp"], apply_norm(lp["ln2"], xx, cfg), cfg))
@@ -263,7 +264,8 @@ def prefill_stack(
             (xx, a), inner = _maybe_scan(
                 remat_wrap(body_dense, s_cfg["remat"]), (xx, jnp.zeros((), jnp.float32)),
                 lp["blocks"], cfg.cross_attn_period, scan=s_cfg["scan_layers"])
-            return (xx, aux + a), {"xk": xk, "xv": xv, "inner": inner}
+            return (xx, aux + a), {"xk": cache_layout(xk, cfg), "xv": cache_layout(xv, cfg),
+                                   "inner": inner}
 
         def body_dense(carry, lp):
             return body(carry, lp)

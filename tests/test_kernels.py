@@ -122,6 +122,19 @@ def test_decode_attention_ring_buffer():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [0, 16])
+def test_decode_attention_head_major_matches_sequence_major(window):
+    """Grouped heads over a head-major (B, K, C, D) cache read what the
+    sequence-major (B, C, K, D) cache gives, ring included, per-row pos."""
+    b, h, k, d, c = 3, 6, 2, 16, 16
+    q, kk, vv = _mk_qkv(jax.random.PRNGKey(5), b, 1, c, h, k, d, jnp.float32)
+    pos = jnp.asarray([3, 15, 37], jnp.int32)
+    want = attn_ref.decode_attention(q, kk, vv, pos, window=window)
+    got = attn_ref.decode_attention(q, jnp.swapaxes(kk, 1, 2), jnp.swapaxes(vv, 1, 2), pos,
+                                    window=window, kv_head_major=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
 # ------------------------------------------------------- attention parity grid
 # (b, s, h, k, d): bucket-boundary and non-pow2 edge shapes the spot checks
 # above never touch — s=96/72/33 exercise the ops' block-alignment fallback.

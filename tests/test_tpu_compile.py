@@ -11,6 +11,10 @@ process at a time may load the TPU's library, and every test worker imports
 every test file.  The persistent compilation cache is off around the
 compiles (an entry compiled for a described chip cannot be read back here).
 """
+import dataclasses
+import math
+from typing import Any, NamedTuple
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -91,47 +95,97 @@ def test_ssd_kernel_compiles_at_mamba2_widths(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.fixture(scope="module")
-def olmo_server(one_chip):
-    """The serve engine at olmo-1b widths, its params as shapes on one chip."""
-    cfg = get_config("olmo-1b")
-    params = jax.eval_shape(lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0))
-    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), params)
-    srv = BatchedServer(None, cfg, capacity=2048, mode="continuous",
-                        settings={"max_batch": 8})
-    return srv, params
+# the serve engine at a deployment's widths: repo config, depth (None as
+# published), cache capacity, slots, and the prefill width compiled
+SERVE = {
+    "olmo-1b": ("olmo-1b", None, 2048, 8, 512),
+    "starcoder2-15b-stage": ("starcoder2-15b", 10, 4096, 32, 2048),
+}
 
 
-def test_serve_prefill_compiles_and_fits(one_chip, olmo_server):
-    srv, params = olmo_server
-    toks = _sds((1, 512), jnp.int32, one_chip)
-    c = srv._prefill_fn.lower(params, toks, None).compile()
-    assert _bytes(c) < HBM_BYTES
+class Compiled(NamedTuple):
+    srv: BatchedServer
+    params: Any            # shapes on one chip
+    width: int             # the prefill width to compile
+    decode: Any            # the donated fused decode step, compiled
+    layer: tuple           # one layer's K (or V) shape in the stacked cache
 
 
 @pytest.fixture(scope="module")
-def olmo_decode(one_chip, olmo_server):
-    """The engine's donated fused decode step, compiled for one chip."""
-    srv, params = olmo_server
-    cfg, mb = srv.cfg, srv.max_batch
-    caches = jax.eval_shape(lambda: M.init_cache(cfg, mb, srv.capacity, srv._enc_len))
-    caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), caches)
-    i32 = _sds((mb,), jnp.int32, one_chip)
-    done = _sds((mb,), jnp.bool_, one_chip)
-    return srv._decode.lower(params, i32, caches, i32, done).compile()
+def serve_compiled(one_chip):
+    """``get(name)``: a deployment's :class:`Compiled`, built once per
+    module."""
+    built = {}
+
+    def get(name):
+        if name not in built:
+            repo_config, layers, capacity, max_batch, width = SERVE[name]
+            cfg = get_config(repo_config)
+            if layers:
+                cfg = dataclasses.replace(cfg, n_layers=layers)
+            params = jax.eval_shape(lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0))
+            params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), params)
+            srv = BatchedServer(None, cfg, capacity=capacity, mode="continuous",
+                                settings={"max_batch": max_batch})
+            caches = jax.eval_shape(lambda: M.init_cache(cfg, max_batch, capacity, srv._enc_len))
+            caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), caches)
+            i32 = _sds((max_batch,), jnp.int32, one_chip)
+            done = _sds((max_batch,), jnp.bool_, one_chip)
+            decode = srv._decode.lower(params, i32, caches, i32, done).compile()
+            built[name] = Compiled(srv, params, width, decode, caches["k"].shape[1:])
+        return built[name]
+
+    return get
 
 
-def test_serve_fused_decode_compiles_and_fits(olmo_decode):
-    assert _bytes(olmo_decode) < HBM_BYTES
+def _prefill_bytes(get, name, one_chip) -> float:
+    d = get(name)
+    toks = _sds((1, d.width), jnp.int32, one_chip)
+    return _bytes(d.srv._prefill_fn.lower(d.params, toks, None).compile())
 
 
-def test_serve_fused_decode_keeps_kv_in_the_stack(olmo_server, olmo_decode):
-    """No buffer shaped like one layer's K or V: the token is written into
-    the stacked cache in place and attention reads its layer where it lies
-    (the copying form materialised four such buffers per layer)."""
-    srv, _ = olmo_server
-    layer = (srv.max_batch, srv.capacity, srv.cfg.n_kv_heads, srv.cfg.hd)
-    text = olmo_decode.as_text()
-    assert shaped_instructions(text, layer, in_fusions=False) == []
-    layer_bytes = 2 * srv.max_batch * srv.capacity * srv.cfg.n_kv_heads * srv.cfg.hd  # bf16
-    assert olmo_decode.memory_analysis().temp_size_in_bytes < layer_bytes
+def _assert_kv_in_stack(get, name) -> None:
+    """No buffer shaped like one layer's K or V, nor like that layer sliced
+    from the stack with its leading 1: the token is written into the stacked
+    cache in place and attention reads its layer where it lies (the copying
+    form materialised four such buffers per layer)."""
+    d = get(name)
+    text = d.decode.as_text()
+    for shape in (d.layer, (1, *d.layer)):
+        assert shaped_instructions(text, shape, in_fusions=False) == [], shape
+    layer_bytes = 2 * math.prod(d.layer)  # bf16
+    assert d.decode.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def test_serve_prefill_compiles_and_fits(one_chip, serve_compiled):
+    assert _prefill_bytes(serve_compiled, "olmo-1b", one_chip) < HBM_BYTES
+
+
+def test_serve_fused_decode_compiles_and_fits(serve_compiled):
+    assert _bytes(serve_compiled("olmo-1b").decode) < HBM_BYTES
+
+
+def test_serve_fused_decode_keeps_kv_in_the_stack(serve_compiled):
+    _assert_kv_in_stack(serve_compiled, "olmo-1b")
+
+
+def test_starcoder2_stage_prefill_compiles_and_fits(one_chip, serve_compiled):
+    """The widest prefill of the code-completion cell: 2048 tokens, grouped
+    KV heads, biased GELU MLP."""
+    assert _prefill_bytes(serve_compiled, "starcoder2-15b-stage", one_chip) < HBM_BYTES
+
+
+def test_starcoder2_stage_fused_decode_compiles_and_fits(serve_compiled):
+    """32 slots of the 4096-token ring beside one stage's weights."""
+    assert _bytes(serve_compiled("starcoder2-15b-stage").decode) < HBM_BYTES
+
+
+def test_starcoder2_stage_fused_decode_keeps_kv_in_the_stack(serve_compiled):
+    """The in-place write and read hold where the cache is a ring (capacity
+    equals the window, the slot is ``pos % C``) and 48 query heads share
+    4 KV heads, whose caches are stored head-major."""
+    d = serve_compiled("starcoder2-15b-stage")
+    cfg = d.srv.cfg
+    assert d.srv.capacity == cfg.window and cfg.n_heads // cfg.n_kv_heads == 12
+    assert d.layer == (32, 4, 4096, 128)
+    _assert_kv_in_stack(serve_compiled, "starcoder2-15b-stage")
