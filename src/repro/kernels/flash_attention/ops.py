@@ -11,12 +11,13 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from ...core.configstore import bucket_pow2
 from ...core.registry import MetricSpec, tunable_component
 from ...core.tunable import Categorical, Int
 from .. import require_tpu
-from . import ref
+from . import kernel, ref
 
 __all__ = ["flash_attention", "decode_attention", "attention_settings",
            "AttentionKernelSettings", "workload_signature"]
@@ -84,8 +85,6 @@ def flash_attention(
         )
     if impl == "pallas":
         require_tpu("flash_attention")
-        from . import kernel
-
         return kernel.flash_attention_pallas(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
             block_q=block_q, block_kv=block_kv,
@@ -93,6 +92,35 @@ def flash_attention(
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0, kv_head_major: bool = False):
-    return ref.decode_attention(q, k_cache, v_cache, pos, window=window,
-                                kv_head_major=kv_head_major)
+def decode_attention(q, k_cache, v_cache, pos, *, kv_head_major: bool = False,
+                     layer: Optional[jax.Array] = None):
+    """One-token attention against a KV cache (``ref.decode_attention``'s
+    semantics; a negative ``pos`` is an empty row, whose output is zeros).
+
+    With ``layer``, the caches are the whole layer stack (L, B, ...) and
+    layer ``layer`` is read where it lies.  The program lowered for a TPU
+    runs :func:`kernel.decode_attention_pallas`, which reads only each row's
+    live blocks, wherever the cache's shape has a ``kernel.decode_block``;
+    any other platform or shape runs the reference."""
+    def reference():
+        k, v = k_cache, v_cache
+        if layer is not None:
+            k, v = (jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False) for t in (k, v))
+        return ref.decode_attention(q, k, v, pos, kv_head_major=kv_head_major)
+
+    if kv_head_major:
+        n_kv, c, d = k_cache.shape[-3:]
+    else:
+        c, n_kv, d = k_cache.shape[-3:]
+    if not kernel.decode_block(c, n_kv, d, kv_head_major):
+        return reference()
+
+    def pallas():
+        k, v, i = k_cache, v_cache, layer
+        if i is None:
+            k, v, i = k[None], v[None], jnp.int32(0)
+        lengths = jnp.where(pos < 0, 0, jnp.minimum(pos + 1, c))
+        lengths = jnp.broadcast_to(lengths, (q.shape[0],))
+        return kernel.decode_attention_pallas(q, k, v, lengths, i, kv_head_major=kv_head_major)
+
+    return jax.lax.platform_dependent(tpu=pallas, default=reference)

@@ -157,7 +157,7 @@ def unrolled_attention(
 
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, pos: jax.Array, *,
-    window: int = 0, scale: Optional[float] = None, kv_head_major: bool = False,
+    scale: Optional[float] = None, kv_head_major: bool = False,
 ) -> jax.Array:
     """Single-token attention against a (possibly ring-buffered) KV cache.
 
@@ -166,9 +166,11 @@ def decode_attention(
     ``pos`` — int32, scalar or per-row ``(B,)``: number of tokens already in
     context (0-based index of the current token).  A vector ``pos`` gives
     every batch row its own validity horizon — the continuous-batching case
-    where each slot decodes at its own sequence position.  For windowed
-    caches (C == window) the cache is a ring buffer indexed ``t % C``;
-    validity is derived from ``pos``.
+    where each slot decodes at its own sequence position.  Row ``b`` attends
+    to slots ``[0, min(pos + 1, C))``: a windowed cache (C == window) is a
+    ring buffer indexed ``t % C``, all of it valid once ``pos >= C``.  A
+    negative ``pos`` is an empty row: it attends to nothing and its output
+    is zeros.
     """
     if kv_head_major:
         b, n_kv, c, d = k_cache.shape
@@ -183,10 +185,7 @@ def decode_attention(
     slot = jnp.arange(c)
     # (1,1) for scalar pos, (B,1) per-row: one mask expression serves both.
     pos_r = jnp.atleast_1d(pos)[:, None]
-    valid = slot[None, :] <= pos_r  # exact while pos < c
-    if window and window == c:
-        # ring buffer: slot holds token t where t ≡ slot (mod c) and t <= pos
-        valid = jnp.where(pos_r >= c, jnp.ones_like(valid), valid)
+    valid = slot[None, :] <= pos_r  # all of a wrapped ring: slot < c <= pos
     s = jnp.where(valid[:, None, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = p.astype(v_cache.dtype)
@@ -197,4 +196,5 @@ def decode_attention(
                                     preferred_element_type=jnp.float32), 3, 1)
     else:
         o = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache, preferred_element_type=jnp.float32)
-    return o.reshape(b, 1, q.shape[2], d).astype(q.dtype)
+    o = jnp.where(pos_r[:, :, None, None] >= 0, o.reshape(b, 1, q.shape[2], d), 0)
+    return o.astype(q.dtype)

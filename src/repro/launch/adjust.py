@@ -87,7 +87,7 @@ def attention_adjustment(cfg: ModelConfig, shape: Shape, mesh: Mesh,
         impl = "unrolled" if shape.kind != "decode" else None
         if shape.kind == "decode":
             out = attn_ops.decode_attention(q, k, v, jnp.asarray(skv - 1, jnp.int32),
-                                            window=cfg.window, kv_head_major=kv_head_major(cfg))
+                                            kv_head_major=kv_head_major(cfg))
         else:
             out = attn_ops.flash_attention(q, k, v, causal=True, window=cfg.window,
                                            impl="unrolled")

@@ -178,7 +178,10 @@ def apply_attn_decode(
     (ring buffer when C == window).  ``pos`` may be a scalar (gang-scheduled
     decode: all rows share one position) or per-row ``(B,)`` (continuous
     batching: each slot carries its own position, rope phase and validity
-    horizon).  Cross-attention caches are static (pre-filled from the
+    horizon).  A negative per-row ``pos`` is an empty row: attention reads
+    nothing for it and returns zeros, and its write lands in slot ``C - 1``
+    of its own row (the serve engine overwrites a row whole before reusing
+    it).  Cross-attention caches are static (pre-filled from the
     encoder/modal source) and not updated.
 
     With ``layer``, the cache leaves are the whole layer stack
@@ -188,17 +191,13 @@ def apply_attn_decode(
     """
     head_major = kv_head_major(cfg)
     c = cache["k"].shape[-2 if head_major else -3]
-
-    def read(t: jax.Array) -> jax.Array:
-        return t if layer is None else jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
-
     if cross:
         q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
         if "bq" in params:
             q = q + params["bq"]
         q = constrain(q, ("batch", None, None, None))
-        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]),
-                                      jnp.asarray(c - 1, jnp.int32), kv_head_major=head_major)
+        y = attn_ops.decode_attention(q, cache["k"], cache["v"], jnp.asarray(c - 1, jnp.int32),
+                                      kv_head_major=head_major, layer=layer)
     else:
         per_row = pos.ndim == 1
         q, k, v = _project_qkv(
@@ -232,8 +231,8 @@ def apply_attn_decode(
                 return jax.lax.dynamic_update_slice(t, u, (*lead, 0, 0, slot, 0) if head_major
                                                     else (*lead, 0, slot, 0, 0))
         cache = dict(k=write(cache["k"], k), v=write(cache["v"], v))
-        y = attn_ops.decode_attention(q, read(cache["k"]), read(cache["v"]), pos,
-                                      window=cfg.window, kv_head_major=head_major)
+        y = attn_ops.decode_attention(q, cache["k"], cache["v"], pos,
+                                      kv_head_major=head_major, layer=layer)
     y = jnp.einsum("bshe,hed->bsd", y, params["wo"])
     if "bo" in params:
         y = y + params["bo"]

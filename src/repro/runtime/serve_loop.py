@@ -52,7 +52,9 @@ from ..core.configstore import bucket_pow2
 from ..core.registry import MetricSpec, tunable_component
 from ..core.telemetry import span
 from ..core.tunable import Int
+from ..kernels.flash_attention.kernel import decode_block
 from ..models import model as M
+from ..models.attention import kv_head_major
 from ..models.config import ModelConfig
 
 __all__ = ["serve_settings", "ServeSettings", "BatchedServer", "workload_signature",
@@ -110,6 +112,7 @@ class _Request:
     done: bool = False
     finished_at: float = 0.0
     slot: int = -1
+    width: int = 0                          # prefill width: the slot's first position
     eff_budget: int = 0                     # resolved (clipped) budget at admission
 
 
@@ -171,7 +174,9 @@ class BatchedServer:
             donate_argnums=(2,), persistent=False)
 
         def _fused_step(p, tok, caches, pos, done):
-            logits, caches = M.decode_step(p, cfg, tok, caches, pos)
+            # a done row (free, or finished until the host syncs) decodes as
+            # an empty row: attention reads none of its cache
+            logits, caches = M.decode_step(p, cfg, tok, caches, jnp.where(done, -1, pos))
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             done = done | (nxt == eos_id)   # EOS tracking stays on device
             return nxt, caches, pos + 1, done
@@ -284,7 +289,7 @@ class BatchedServer:
             self._caches, self._tok, self._pos, self._done = self._install(
                 self._caches, small, jnp.asarray(slot, jnp.int32), self._tok,
                 self._pos, self._done, logits, jnp.asarray(width, jnp.int32))
-        r.slot = slot
+        r.slot, r.width = slot, width
         r.eff_budget = self._eff_budget(r, width)
         self._slot_req[slot] = r
 
@@ -341,15 +346,16 @@ class BatchedServer:
 
         Each phase is a span on the profiler's host timeline (``serve.step``
         around ``serve.admit`` ⊃ ``serve.prefill`` per admitted request,
-        ``serve.decode``, ``serve.sync`` ⊃ ``serve.fetch``, and
-        ``serve.telemetry``); none wraps per-token work."""
+        ``serve.decode`` with its ``kv_read_share``, ``serve.sync`` ⊃
+        ``serve.fetch``, and ``serve.telemetry``); none wraps per-token
+        work."""
         with span("serve.step", sync=self.decode_syncs):
             with span("serve.admit", queued=len(self.queue)):
                 self._admit()
             if not self._n_live():
                 return []
             emitted = []
-            with span("serve.decode", n=self.sync_interval):
+            with span("serve.decode", n=self.sync_interval, **self._kv_read()):
                 for _ in range(self.sync_interval):
                     # emit-input scheme: each step CONSUMES self._tok (writes its
                     # KV at pos and predicts the next), so the stream of step
@@ -365,6 +371,25 @@ class BatchedServer:
             with span("serve.telemetry"):
                 self._emit_rolling()
             return finished
+
+    def _kv_read(self) -> Dict[str, float]:
+        """``kv_read_share`` of the coming ``sync_interval`` decodes: the
+        self-attention KV blocks they read (``decode_block``), over
+        ``max_batch x C / block`` a decode.  From the host's slot table
+        alone: a live slot's decode at position ``width + emitted`` reads
+        that many tokens plus one (all of a wrapped ring), rounded up to the
+        block; an empty slot reads nothing.  A row that meets EOS between
+        syncs is counted to the sync.  Empty for a model without such a
+        cache, or one whose shape has no block (decode reads it whole)."""
+        c = self.cfg.cache_len(self.capacity)
+        blk = self.cfg.family != "ssm" and decode_block(
+            c, self.cfg.n_kv_heads, self.cfg.hd, kv_head_major(self.cfg))
+        if not blk:
+            return {}
+        n = self.sync_interval
+        read = sum(-(-min(r.width + len(r.tokens) + t + 1, c) // blk)
+                   for r in self._slot_req if r is not None for t in range(n))
+        return {"kv_read_share": read / (n * self.max_batch * (c // blk))}
 
     def _sync(self, emitted: List[jax.Array]) -> List[_Request]:
         self.decode_syncs += 1

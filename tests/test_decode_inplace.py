@@ -154,3 +154,31 @@ def test_donated_decode_holds_no_copy_of_a_layer_cache():
     text = step.lower(params, vec, caches, vec).compile().as_text()
     assert jax.tree.leaves(caches)[0].shape == (2, b, c, cfg.n_kv_heads, cfg.hd)
     assert shaped_instructions(text, (b, c, cfg.n_kv_heads, cfg.hd)) == []
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "starcoder2-15b"])
+def test_empty_rows_leave_live_rows_bit_for_bit(arch):
+    """The serve engine decodes a done row at position -1, an empty row that
+    attention reads nothing for.  Live rows' logits and caches are what they
+    were when a done row kept decoding at its own growing position (past
+    the cache's end here), bit for bit; sequence-major MHA and a head-major
+    grouped ring."""
+    cfg = get_config(arch).reduced().validate()
+    params = M.init_params(jax.random.PRNGKey(3), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (4, S), 0, cfg.vocab_size)
+    logits, caches, _ = M.prefill(params, cfg, tokens, cache_capacity=CAPACITY)
+    c = cfg.cache_len(CAPACITY)
+    pos = jnp.array([S, 2 * c + 1, S - 3, 5 * c], jnp.int32)
+    done = np.array([False, True, False, True])
+    step = jax.jit(lambda p, t, cs, q: M.decode_step(p, cfg, t, cs, q))
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    before, after = caches, caches
+    for n in range(STEPS):
+        want, before = step(params, tok, before, pos + n)
+        got, after = step(params, tok, after, jnp.where(done, -1, pos + n))
+        np.testing.assert_array_equal(np.asarray(got)[~done], np.asarray(want)[~done],
+                                      err_msg=f"step {n}")
+        for g, w in zip(jax.tree.leaves(after), jax.tree.leaves(before)):
+            np.testing.assert_array_equal(np.asarray(g)[:, ~done], np.asarray(w)[:, ~done],
+                                          err_msg=f"step {n}")
+        tok = jnp.argmax(want, -1).astype(jnp.int32)

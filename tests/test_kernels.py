@@ -118,7 +118,7 @@ def test_decode_attention_ring_buffer():
         ring_k[:, t % w] = np.asarray(kk[:, t])
         ring_v[:, t % w] = np.asarray(vv[:, t])
     got = attn_ref.decode_attention(q[:, -1:], jnp.asarray(ring_k), jnp.asarray(ring_v),
-                                    jnp.asarray(total - 1, jnp.int32), window=w)
+                                    jnp.asarray(total - 1, jnp.int32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
@@ -128,11 +128,88 @@ def test_decode_attention_head_major_matches_sequence_major(window):
     sequence-major (B, C, K, D) cache gives, ring included, per-row pos."""
     b, h, k, d, c = 3, 6, 2, 16, 16
     q, kk, vv = _mk_qkv(jax.random.PRNGKey(5), b, 1, c, h, k, d, jnp.float32)
-    pos = jnp.asarray([3, 15, 37], jnp.int32)
-    want = attn_ref.decode_attention(q, kk, vv, pos, window=window)
+    # a ring (window == C) may run past its end; a full cache may not
+    pos = jnp.asarray([3, 15, 37 if window == c else 9], jnp.int32)
+    want = attn_ref.decode_attention(q, kk, vv, pos)
     got = attn_ref.decode_attention(q, jnp.swapaxes(kk, 1, 2), jnp.swapaxes(vv, 1, 2), pos,
-                                    window=window, kv_head_major=True)
+                                    kv_head_major=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
+def test_decode_attention_negative_pos_is_an_empty_row():
+    """A negative ``pos`` marks an empty row: zeros, and the other rows
+    exactly what they read without it."""
+    b, h, k, d, c = 3, 4, 2, 16, 16
+    q, kk, vv = _mk_qkv(jax.random.PRNGKey(6), b, 1, c, h, k, d, jnp.float32)
+    live = attn_ref.decode_attention(q, kk, vv, jnp.asarray([5, 5, 20], jnp.int32))
+    got = attn_ref.decode_attention(q, kk, vv, jnp.asarray([-1, 5, 20], jnp.int32))
+    assert not np.asarray(got[0]).any()
+    np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(live[1:]))
+
+
+# (C, K, D, head-major) -> tokens a block: olmo-1b, StarCoder2's ring, an
+# odd capacity; 0 where the TPU's tiling refuses the copy (D of 64 as in
+# hymba and seamless, an odd C head-major, K not a multiple of 8
+# sequence-major), and the reference runs
+DECODE_BLOCKS = [
+    ((2048, 16, 128, False), 256),
+    ((4096, 4, 128, True), 1024),
+    ((100, 16, 128, False), 4),
+    ((2048, 5, 64, True), 0),
+    ((512, 16, 64, False), 0),
+    ((100, 8, 128, True), 0),
+    ((256, 12, 128, False), 0),
+]
+
+
+@pytest.mark.parametrize("shape,blk", DECODE_BLOCKS)
+def test_decode_block_from_the_cache_shape(shape, blk):
+    from repro.kernels.flash_attention.kernel import decode_block
+
+    assert decode_block(*shape) == blk
+
+
+# (kv_head_major, K, H, D): both cache orders, one query per KV head and
+# grouped; C is two blocks of ``decode_block`` at each shape
+DECODE_KERNEL = {
+    "seq_mha": (False, 16, 16, 128),
+    "seq_grouped": (False, 8, 16, 128),
+    "head_mha": (True, 8, 8, 128),
+    "head_grouped": (True, 4, 12, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_KERNEL))
+def test_decode_attention_pallas_vs_ref(case):
+    """The decode kernel (interpret mode) against ``ref.decode_attention`` on
+    layer 1 of a two-layer stack, in the configured precision (bf16 cache,
+    f32 scores).  Rows: empty, shorter than a block, ending on a block edge,
+    one token into the second block, full, and a ring wrapped past C."""
+    from repro.kernels.flash_attention.kernel import decode_attention_pallas, decode_block
+
+    head_major, n_kv, h, d = DECODE_KERNEL[case]
+    blk = decode_block(1 << 30, n_kv, d, head_major)
+    c, n_layers, layer = 2 * blk, 2, 1
+    pos = jnp.asarray([-1, 40, blk - 1, blk, c - 1, c + 300], jnp.int32)
+    b = pos.shape[0]
+    kq, kk, kv = jax.random.split(_seeded_key("decode_pallas", case), 3)
+    shape = (n_layers, b, n_kv, c, d) if head_major else (n_layers, b, c, n_kv, d)
+    ks = jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16)
+    vs = jax.random.normal(kv, shape, jnp.float32).astype(jnp.bfloat16)
+    q = jax.random.normal(kq, (b, 1, h, d), jnp.float32).astype(jnp.bfloat16)
+    assert decode_block(c, n_kv, d, head_major) == blk
+    lengths = jnp.where(pos < 0, 0, jnp.minimum(pos + 1, c))
+    got = decode_attention_pallas(q, ks, vs, lengths, jnp.int32(layer),
+                                  kv_head_major=head_major, interpret=True)
+    want = attn_ref.decode_attention(q, ks[layer], vs[layer], pos, kv_head_major=head_major)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert not np.asarray(got[0]).any()
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, **_tol(jnp.bfloat16))
+    # no less precise than the reference: both against float32 throughout
+    f32 = [t.astype(jnp.float32) for t in (q, ks[layer], vs[layer])]
+    exact = np.asarray(attn_ref.decode_attention(*f32, pos, kv_head_major=head_major))
+    assert np.abs(got - exact).max() <= 1.5 * np.abs(want - exact).max()
 
 
 # ------------------------------------------------------- attention parity grid

@@ -56,6 +56,42 @@ def _token_streams(server):
     return {r.rid: list(r.tokens) for r in server.results.values()}
 
 
+# -------------------------------------------------------- KV read counter
+def test_kv_read_share_counts_the_blocks_decode_reads():
+    """``kv_read_share`` from a known slot table, by hand.  olmo-1b at 2048
+    slots reads blocks of 256 tokens; 8 slots, 4 decodes a sync.  Slot 0:
+    width 512, nothing emitted: it reads 513-516 tokens, 3 blocks each.
+    Slot 3: width 1024, 250 emitted: 1275-1278, 5 each.  Slot 5: width 128,
+    127 emitted: 256 (1 block), then 257-259 (2 each).  The rest are empty:
+    (12 + 20 + 7) / (4 x 8 x 8)."""
+    from repro.kernels.flash_attention.kernel import decode_block
+
+    cfg = get_config("olmo-1b")
+    srv = BatchedServer(None, cfg, capacity=2048, mode="continuous",
+                        settings={"max_batch": 8, "sync_interval": 4})
+    assert decode_block(2048, cfg.n_kv_heads, cfg.hd, False) == 256
+    for slot, width, emitted in ((0, 512, 0), (3, 1024, 250), (5, 128, 127)):
+        srv._slot_req[slot] = serve_loop._Request(slot, np.zeros(1, np.int32), 0.0,
+                                                  slot=slot, width=width,
+                                                  tokens=[7] * emitted)
+    assert srv._kv_read() == {"kv_read_share": (12 + 20 + 7) / (4 * 8 * 8)}
+
+
+def test_kv_read_share_reads_all_of_a_wrapped_ring():
+    """StarCoder2's 4096-token ring (capacity = window) reads blocks of 1024:
+    a slot past the ring's end reads all 4 a decode, one at width 2048 with
+    2 emitted reads 2051-2052 tokens, 3 blocks; 32 slots, 2 decodes a sync."""
+    cfg = get_config("starcoder2-15b")
+    srv = BatchedServer(None, cfg, capacity=4096, mode="continuous",
+                        settings={"max_batch": 32, "sync_interval": 2})
+    assert cfg.cache_len(4096) == cfg.window == 4096
+    for slot, width, emitted in ((1, 2048, 2100), (2, 2048, 2)):
+        srv._slot_req[slot] = serve_loop._Request(slot, np.zeros(1, np.int32), 0.0,
+                                                  slot=slot, width=width,
+                                                  tokens=[7] * emitted)
+    assert srv._kv_read() == {"kv_read_share": (2 * 4 + 2 * 3) / (2 * 32 * 4)}
+
+
 # ------------------------------------------------------- scheduler identity
 def test_mixed_prompt_lengths_match_sequential_reference(served):
     """Mixed widths across slots: continuous output == one-at-a-time gang."""

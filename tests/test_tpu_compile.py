@@ -22,7 +22,7 @@ from conftest import shaped_instructions
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.flash_attention.kernel import decode_attention_pallas, flash_attention_pallas
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.ssd.kernel import ssd_pallas
 from repro.models import model as M
@@ -69,6 +69,56 @@ def test_flash_attention_kernel_compiles(one_chip):
     c = _compile(lambda q, k, v: flash_attention_pallas(
         q, k, v, causal=True, block_q=512, block_kv=512), q, q, q)
     assert "tpu_custom_call" in c.as_text()
+
+
+# the decode kernel at both cells' widths: (layers, slots, C, K, head-major)
+DECODE = {
+    "olmo-1b": (16, 32, 2048, 16, False),
+    "starcoder2-15b-stage": (10, 32, 4096, 4, True),
+}
+
+
+@pytest.mark.parametrize("name", list(DECODE))
+def test_decode_attention_kernel_compiles(one_chip, name):
+    """The whole stacked cache goes in (no layer is sliced out for it), the
+    kernel copies what it reads, and nothing else is allocated."""
+    n_layers, b, c, n_kv, head_major = DECODE[name]
+    h = 48 if head_major else n_kv
+    layer = (b, n_kv, c, 128) if head_major else (b, c, n_kv, 128)
+    kv = _sds((n_layers, *layer), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        lambda q, k, v, n, i: decode_attention_pallas(q, k, v, n, i, kv_head_major=head_major),
+        _sds((b, 1, h, 128), jnp.bfloat16, one_chip), kv, kv,
+        _sds((b,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# (K, H, D, head-major, kernel?): the kernel where ``decode_block`` finds a
+# block, the reference where the TPU's tiling refuses the copy (D of 64:
+# hymba head-major, seamless sequence-major)
+DECODE_DISPATCH = [
+    (16, 16, 128, False, True),
+    (4, 12, 128, True, True),
+    (5, 25, 64, True, False),
+    (16, 16, 64, False, False),
+]
+
+
+@pytest.mark.parametrize("n_kv,h,d,head_major,kernel", DECODE_DISPATCH)
+def test_decode_attention_op_lowers_for_the_tpu(one_chip, n_kv, h, d, head_major, kernel):
+    """``ops.decode_attention`` on a stacked cache, lowered for the TPU,
+    compiles either way and holds the kernel exactly where it can run."""
+    from repro.kernels.flash_attention import ops
+
+    layer = (4, n_kv, 512, d) if head_major else (4, 512, n_kv, d)
+    kv = _sds((2, *layer), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        lambda q, k, v, p, i: ops.decode_attention(q, k, v, p, kv_head_major=head_major,
+                                                   layer=i),
+        _sds((4, 1, h, d), jnp.bfloat16, one_chip), kv, kv,
+        _sds((4,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
 
 
 @pytest.mark.parametrize("residual", [False, True])
@@ -189,3 +239,16 @@ def test_starcoder2_stage_fused_decode_keeps_kv_in_the_stack(serve_compiled):
     assert d.srv.capacity == cfg.window and cfg.n_heads // cfg.n_kv_heads == 12
     assert d.layer == (32, 4, 4096, 128)
     _assert_kv_in_stack(serve_compiled, "starcoder2-15b-stage")
+
+
+@pytest.mark.parametrize("name", list(SERVE))
+def test_serve_fused_decode_runs_the_decode_kernel(serve_compiled, name):
+    """Lowered for the TPU, decode attention dispatches to the Pallas
+    kernel: one ``decode_attention`` custom call in the layer loop, handed
+    the whole stacked K and V."""
+    d = serve_compiled(name)
+    calls = [line for line in d.decode.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].lstrip().startswith("%decode_attention")
+    stack = "bf16[" + ",".join(map(str, (d.srv.cfg.n_layers, *d.layer))) + "]"
+    assert calls[0].count(stack) == 2

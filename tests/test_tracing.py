@@ -92,6 +92,27 @@ def test_one_admitting_step_gives_the_span_tree(served, tmp_path):
     assert args["wait_us"] >= 0
 
 
+def test_decode_span_counts_the_kv_blocks_read(tmp_path):
+    """With KV heads the decode kernel can copy (8 of 128; the reduced
+    config's heads of 16 have no block, and its spans no counter),
+    ``serve.decode`` carries ``kv_read_share``: 2 live slots of 4, each
+    reading its whole 32-token row, one block, at each of 3 decodes."""
+    import dataclasses
+
+    import jax
+    cfg = dataclasses.replace(get_config("olmo-1b").reduced(), n_heads=8, n_kv_heads=8,
+                              head_dim=128).validate()
+    srv = BatchedServer(M.init_params(jax.random.PRNGKey(0), cfg), cfg, capacity=CAPACITY,
+                        eos_id=-1, mode="continuous",
+                        settings={"max_batch": 4, "sync_interval": 3})
+    srv.submit(_prompts(1, seed=1)[0])
+    srv.step()                                 # compiles outside the trace
+    srv.submit(_prompts(1, seed=2)[0])
+    _, ev = _traced(tmp_path, srv.step)
+    (decode,) = [e for e in ev if e[0] == "serve.decode"]
+    assert decode[3] == {"n": 3, "kv_read_share": 0.5}
+
+
 def test_a_step_with_nothing_to_admit_has_no_prefill(served, tmp_path):
     srv = _server(served)
     srv.submit(_prompts(1, seed=3)[0], budget=20)
