@@ -88,23 +88,6 @@ def check_compile_cold_warm(expect_quick: Optional[bool] = None) -> None:
     assert d["counters"]["misses"] >= 1, d["counters"]
 
 
-def check_serve_scenarios(expect_quick: Optional[bool] = None) -> None:
-    d = _load("serve_scenarios", expect_quick)
-    assert set(d["scenarios"]) == {"diurnal", "bursts", "heavy_tail"}, d["scenarios"].keys()
-    for name, row in d["scenarios"].items():
-        for mode in ("gang", "continuous"):
-            assert len(row[mode]["tokens_per_s"]) >= 2, (name, mode)
-            assert all(s > 0 for s in row[mode]["tokens_per_s"]), (name, mode)
-            assert all(s >= 0 for s in row[mode]["p99_latency_s"]), (name, mode)
-        # identical offered work on both sides, or the A/B is bogus
-        assert row["gang"]["total_tokens"] == row["continuous"]["total_tokens"], name
-    v = d["heavy_tail_verdict"]
-    assert v["verdict"] == "improved", (
-        f"continuous batching did not beat gang scheduling on the heavy-tail "
-        f"mix: {v}")
-    assert v["candidate_location"] > v["baseline_location"], v
-
-
 def check_multi_instance(expect_quick: Optional[bool] = None) -> None:
     d = _load("multi_instance", expect_quick)
     assert d["instances"], "no instances recorded"
@@ -169,7 +152,6 @@ CHECKS = {
     "multi_instance": check_multi_instance,
     "campaign_sweep": check_campaign_sweep,
     "compile_cold_warm": check_compile_cold_warm,
-    "serve_scenarios": check_serve_scenarios,
     "online_tuning": check_online_tuning,
     "fault_tolerance": check_fault_tolerance,
 }

@@ -68,7 +68,7 @@ ONLINE_KNOBS = ("sync_interval",)
 
 def _server(params, cfg, settings: Dict[str, int]) -> BatchedServer:
     return BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                         mode="continuous", settings=dict(settings))
+                         settings=dict(settings))
 
 
 def _warmup(params, cfg) -> None:

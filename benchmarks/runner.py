@@ -39,7 +39,7 @@ REGISTRY: Dict[str, str] = {
     name: f"benchmarks.{name}" for name in (
         "optimizer_throughput", "configstore_roundtrip", "multi_instance",
         "kernel_autotune", "campaign_sweep", "compile_cold_warm",
-        "serve_scenarios", "online_tuning", "fault_tolerance")
+        "online_tuning", "fault_tolerance")
 }
 
 
@@ -76,7 +76,6 @@ SMOKE_CHECKS = {
     "kernel_autotune": "kernel_autotune",
     "campaign_sweep": "campaign_sweep",
     "compile_cold_warm": "compile_cold_warm",
-    "serve_scenarios": "serve_scenarios",
     "online_tuning": "online_tuning",
     "fault_tolerance": "fault_tolerance",
 }

@@ -72,7 +72,7 @@ def serve_phase(cfg: ModelConfig, *, capacity: int, max_batch: int, max_new_toke
     """Serve ``n_requests`` seeded prompts through the continuous engine."""
     dev = jax.devices()[0]
     params = M.init_params(jax.random.PRNGKey(seed), cfg)
-    srv = BatchedServer(params, cfg, capacity=capacity, mode="continuous",
+    srv = BatchedServer(params, cfg, capacity=capacity,
                         settings={"max_batch": max_batch, "max_new_tokens": max_new_tokens})
     rng = np.random.default_rng(seed)
     lens = rng.integers(prompt_lens[0], prompt_lens[1] + 1, size=n_requests)

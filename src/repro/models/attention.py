@@ -175,14 +175,14 @@ def apply_attn_decode(
     """One-token attention against (and update of) a KV cache.
 
     For self-attention the new token's K/V are written at slot ``pos % C``
-    (ring buffer when C == window).  ``pos`` may be a scalar (gang-scheduled
-    decode: all rows share one position) or per-row ``(B,)`` (continuous
-    batching: each slot carries its own position, rope phase and validity
-    horizon).  A negative per-row ``pos`` is an empty row: attention reads
-    nothing for it and returns zeros, and its write lands in slot ``C - 1``
-    of its own row (the serve engine overwrites a row whole before reusing
-    it).  Cross-attention caches are static (pre-filled from the
-    encoder/modal source) and not updated.
+    (ring buffer when C == window).  ``pos`` may be a scalar (one position for
+    every row: ``runtime/steps.make_decode_step``, the dry run, tests) or
+    per-row ``(B,)`` (continuous batching: each slot carries its own
+    position, rope phase and validity horizon).  A negative per-row ``pos``
+    is an empty row: attention reads nothing for it and returns zeros, and
+    its write lands in slot ``C - 1`` of its own row (the serve engine
+    overwrites a row whole before reusing it).  Cross-attention caches are
+    static (pre-filled from the encoder/modal source) and not updated.
 
     With ``layer``, the cache leaves are the whole layer stack
     ``(L, B, ...)``: the token is written into layer ``layer`` of the stack

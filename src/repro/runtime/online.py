@@ -6,10 +6,10 @@ the store once at startup.  :class:`OnlineTuner` closes the loop the way the
 SPE-in-DevOps literature demands: optimization runs *continuously against
 live traffic*, gated by the same measurement discipline as everything else.
 
-The controller wraps a continuous-mode :class:`~.serve_loop.BatchedServer`
-and interposes at sync boundaries only (it is a drop-in server for
-:func:`~.traffic.replay` — same ``submit``/``step``/``drain``/``run``
-surface):
+The controller wraps a :class:`~.serve_loop.BatchedServer` (the
+continuous-batching engine) and interposes at sync boundaries only (it is a
+drop-in server for :func:`~.traffic.replay` — same
+``submit``/``step``/``drain``/``run`` surface):
 
   * The server's windowed telemetry (``tokens_per_s``, ``p50_latency_s``,
     ``queue_depth`` — one record per sync interval, see
@@ -120,7 +120,7 @@ class OnlineJournal:
 
 
 class OnlineTuner:
-    """Shadow/canary tuner wrapped around a live continuous-batching server.
+    """Shadow/canary tuner wrapped around a live :class:`~.serve_loop.BatchedServer`.
 
     Drive it exactly like the server it wraps — ``submit``/``step``/``drain``
     /``run``/``begin_run``/``finish_run`` all work, and
@@ -145,9 +145,6 @@ class OnlineTuner:
                  budget: int = 8, windows_per_eval: int = 4,
                  objective: str = "tokens_per_s", mode: str = "max",
                  alpha: float = 0.05, min_effect: float = 0.05, seed: int = 0):
-        if server.mode != "continuous":
-            raise ValueError("OnlineTuner requires a continuous-mode server "
-                             "(gang mode has no sync boundaries to swap at)")
         self.server = server
         self.store = store if store is not None else default_store()
         self.meta = get_component("serve_batching")

@@ -1,19 +1,12 @@
 """Serving loop: slot-level continuous batching with amortized host sync.
 
-Two schedulers over one compiled-artifact family:
-
-  * ``mode="continuous"`` (default) — a slot-level engine.  Each of the
-    ``max_batch`` slots carries its own device state (current token, position,
-    done flag, cache rows); a finished sequence frees its slot at the next
-    sync and a waiting request is prefilled *into* that slot while every
-    other slot keeps decoding.  EOS detection runs on device inside the
-    fused decode step, and the host reads token batches back only every
-    ``sync_interval`` steps — one device→host sync per interval instead of
-    one per token.
-  * ``mode="gang"`` — the static-batching baseline: admit a full batch,
-    decode until everyone finishes, sync every token.  Kept honest (same
-    bucketed prefill, same per-request budgets) so benchmark comparisons
-    measure the scheduler, not incidental fixes.
+One scheduler: each of the ``max_batch`` slots carries its own device state
+(current token, position, done flag, cache rows); a finished sequence frees
+its slot at the next sync and a waiting request is prefilled *into* that
+slot while every other slot keeps decoding.  EOS detection runs on device
+inside the fused decode step, and the host reads token batches back only
+every ``sync_interval`` steps — one device→host sync per interval instead
+of one per token.
 
 Scheduler contract:
 
@@ -122,8 +115,10 @@ class BatchedServer:
     Static shapes (batch = max_batch, cache = capacity) keep one compiled
     decode step for the whole run; empty slots decode garbage that is
     discarded.  ``settings`` pins explicit tunable values (benchmarks use it
-    to compare schedulers without touching the tuned store); anything not
+    to fix a configuration without touching the tuned store); anything not
     pinned resolves through ``serve_settings.settings_for(workload)``.
+    ``mode`` accepts only ``"continuous"``: callers that still pass it keep
+    working, and anything else raises.
     ``emitter`` (a :class:`repro.core.telemetry.TelemetryEmitter` bound to
     the ``serve_batching`` meta) streams rolling tokens/s, p50 latency,
     queue depth and live slots — the agent path sees the same metrics the
@@ -134,10 +129,9 @@ class BatchedServer:
                  eos_id: int = 1, workload: Optional[str] = None,
                  mode: str = "continuous", settings: Optional[Dict[str, int]] = None,
                  emitter: Optional[Any] = None):
-        if mode not in ("continuous", "gang"):
+        if mode != "continuous":
             raise ValueError(f"unknown serve mode {mode!r}")
         self.params, self.cfg, self.capacity, self.eos_id = params, cfg, capacity, eos_id
-        self.mode = mode
         self.emitter = emitter
         self.workload = workload or workload_signature(cfg.family, capacity)
         s = serve_settings.settings_for(self.workload)
@@ -154,7 +148,7 @@ class BatchedServer:
         sig = config_signature(cfg)
         # Context-keyed compiled steps: two servers over the same (config,
         # capacity, batch) share compiled artifacts in-process.  The KV
-        # caches are donated in both decode steps — each iteration rebinds
+        # caches are donated in the decode step — each iteration rebinds
         # them, so XLA may update in place instead of copying the full
         # cache per token.  Donation rules out persistence (deserializing a
         # donating executable is a use-after-free, see cached_jit); per-token
@@ -167,11 +161,6 @@ class BatchedServer:
             key="serve.prefill",
             context=(sig, self.workload, capacity),
             persistent=True)
-        self._gang_decode = cached_jit(
-            lambda p, tok, caches, pos: M.decode_step(p, cfg, tok, caches, pos),
-            key="serve.decode_step",
-            context=(sig, self.workload, capacity, self.max_batch),
-            donate_argnums=(2,), persistent=False)
 
         def _fused_step(p, tok, caches, pos, done):
             # a done row (free, or finished until the host syncs) decodes as
@@ -205,7 +194,7 @@ class BatchedServer:
         self.queue: Deque[_Request] = deque()
         self.results: Dict[int, _Request] = {}
         self._next_rid = 0
-        # per-slot device state (continuous mode); empty slots start done
+        # per-slot device state; empty slots start done
         self._slot_req: List[Optional[_Request]] = [None] * self.max_batch
         self._free: List[int] = list(range(self.max_batch))
         self._caches = None                 # lazily built on first admission
@@ -239,17 +228,17 @@ class BatchedServer:
         keep = min(n_prompt, max(2, self.capacity // 2))
         return max(2, bucket_pow2(keep))
 
-    def _pad_prompts(self, reqs: List[_Request], rows: int, width: int) -> np.ndarray:
-        toks = np.zeros((rows, width), np.int32)
-        for i, r in enumerate(reqs):
-            n = min(len(r.prompt), width)
-            if n:
-                toks[i, -n:] = r.prompt[-n:]  # left-pad; keep the prompt tail
+    @staticmethod
+    def _pad_prompt(r: _Request, width: int) -> np.ndarray:
+        toks = np.zeros((1, width), np.int32)
+        n = min(len(r.prompt), width)
+        if n:
+            toks[0, -n:] = r.prompt[-n:]      # left-pad; keep the prompt tail
         return toks
 
-    def _modal(self, rows: int) -> Optional[jax.Array]:
+    def _modal(self) -> Optional[jax.Array]:
         if self.cfg.family in ("encdec", "vlm"):
-            return jnp.zeros((rows, self._enc_len, self.cfg.d_model), jnp.float32)
+            return jnp.zeros((1, self._enc_len, self.cfg.d_model), jnp.float32)
         return None
 
     def _eff_budget(self, r: _Request, width: int) -> int:
@@ -281,9 +270,9 @@ class BatchedServer:
             if self._caches is None:
                 self._caches = M.init_cache(self.cfg, self.max_batch, self.capacity,
                                             self._enc_len)
-            toks = self._pad_prompts([r], 1, width)
+            toks = self._pad_prompt(r, width)
             logits, small, _ = self._prefill_fn(self.params, jnp.asarray(toks),
-                                                self._modal(1))
+                                                self._modal())
             # first token stays on device: it flows into the decode stream and
             # reaches the host with the next batched sync, not here
             self._caches, self._tok, self._pos, self._done = self._install(
@@ -438,13 +427,10 @@ class BatchedServer:
         return m
 
     def drain(self) -> None:
-        """Serve everything currently queued under this mode's scheduler
-        WITHOUT resetting per-run accounting (open-loop replay primitive)."""
-        if self.mode == "gang":
-            self._run_gang()
-        else:
-            while self.queue or self._n_live():
-                self.step()
+        """Serve everything currently queued WITHOUT resetting per-run
+        accounting (open-loop replay primitive)."""
+        while self.queue or self._n_live():
+            self.step()
 
     def run(self, max_new_tokens: Optional[int] = None) -> Dict[str, float]:
         """Serve everything currently queued; returns throughput metrics
@@ -452,54 +438,6 @@ class BatchedServer:
         self._begin_run(max_new_tokens)
         self.drain()
         return self.finish_run()
-
-    # ----------------------------------------------------------- gang mode
-    def _run_gang(self) -> None:
-        """Static-batching baseline: admit a batch, decode until every member
-        finishes (or budgets out), sync every token."""
-        while self.queue:
-            live = [self.queue.popleft()
-                    for _ in range(min(self.max_batch, len(self.queue)))]
-            width = self._width_of(max(len(r.prompt) for r in live))
-            toks = self._pad_prompts(live, self.max_batch, width)
-            logits, caches, pos = self._prefill_fn(self.params, jnp.asarray(toks),
-                                                   self._modal(self.max_batch))
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            budgets = [self._eff_budget(r, width) for r in live]
-            t_host = _host_fetch(tok)
-            self.decode_syncs += 1
-            self._run_syncs += 1
-            for i, r in enumerate(live):
-                r.tokens.append(int(t_host[i]))
-                self._win_tokens += 1
-                if r.tokens[-1] == self.eos_id or len(r.tokens) >= budgets[i]:
-                    r.done = True
-            for _ in range(max(budgets) - 1):
-                if all(r.done for r in live):
-                    break
-                logits, caches = self._gang_decode(self.params, tok, caches, pos)
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                pos = pos + 1
-                self.decode_steps += 1
-                self._run_steps += 1
-                t_host = _host_fetch(tok)     # the per-token sync the
-                self.decode_syncs += 1        # continuous engine amortizes
-                self._run_syncs += 1
-                for i, r in enumerate(live):
-                    if not r.done:
-                        nxt = int(t_host[i])
-                        r.tokens.append(nxt)
-                        self._win_tokens += 1
-                        if nxt == self.eos_id or len(r.tokens) >= budgets[i]:
-                            r.done = True
-            now = time.perf_counter()
-            for r in live:                    # gang: nobody leaves early
-                r.done = True
-                r.finished_at = now
-                self.results[r.rid] = r
-                self._run_completed.append(r)
-                self._win_completed.append(r)
-            self._emit_rolling()
 
     # -------------------------------------------------------------- metrics
     def _metrics(self, completed: List[_Request], dt: float) -> Dict[str, float]:

@@ -7,14 +7,9 @@ open-loop — arrivals land at their scheduled times whether or not the server
 has kept up, so queueing delay shows up in latency instead of silently
 stretching the offered load.
 
-Three canonical mixes:
-
-  * ``diurnal``  — sinusoidally modulated Poisson arrivals (the daily ramp).
-  * ``bursts``   — clumped arrivals: quiet gaps then near-simultaneous spikes.
-  * ``heavy_tail`` — Poisson arrivals whose OUTPUT budgets are bimodal
-    (mostly short, a long tail) — the convoy-effect scenario where gang
-    scheduling stalls a whole batch behind its slowest member and
-    continuous batching backfills freed slots.
+One mix, ``drifting``: Poisson arrivals whose output budgets flip from long
+decode-heavy completions to short chat turns mid-scenario — the traffic
+shift the online tuner (:mod:`repro.runtime.online`) must adapt to.
 
 Generators live in ``runtime`` (not ``benchmarks/``) so campaign measures
 can replay the same mixes without importing benchmark harnesses.
@@ -27,8 +22,7 @@ from typing import Callable, Dict, List
 
 import numpy as np
 
-__all__ = ["Arrival", "SCENARIOS", "diurnal", "bursts", "heavy_tail", "drifting",
-           "replay"]
+__all__ = ["Arrival", "SCENARIOS", "drifting", "replay"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,52 +38,6 @@ def _prompt(rng: np.random.Generator, n: int, vocab: int) -> np.ndarray:
 
 def _poisson_times(rng: np.random.Generator, n: int, rate: float) -> np.ndarray:
     return np.cumsum(rng.exponential(1.0 / rate, size=n))
-
-
-def diurnal(seed: int, n: int = 32, base_rate: float = 8.0, period: float = 4.0,
-            vocab: int = 250) -> List[Arrival]:
-    """Inhomogeneous Poisson ramp: rate(t) = base * (1 + 0.8 sin(2πt/period))."""
-    rng = np.random.default_rng(seed)
-    out, t = [], 0.0
-    for _ in range(n):
-        rate = base_rate * (1.0 + 0.8 * np.sin(2.0 * np.pi * t / period))
-        t += float(rng.exponential(1.0 / max(rate, 1e-3)))
-        out.append(Arrival(t, _prompt(rng, rng.integers(3, 17), vocab),
-                           int(rng.integers(4, 13))))
-    return out
-
-
-def bursts(seed: int, n: int = 32, burst_size: int = 8, gap: float = 1.0,
-           vocab: int = 250) -> List[Arrival]:
-    """Clumped arrivals: quiet exponential gaps, then a near-simultaneous
-    burst of ``burst_size`` requests."""
-    rng = np.random.default_rng(seed)
-    out, t = [], 0.0
-    while len(out) < n:
-        t += float(rng.exponential(gap))
-        for _ in range(min(burst_size, n - len(out))):
-            t += float(rng.exponential(0.005))
-            out.append(Arrival(t, _prompt(rng, rng.integers(3, 13), vocab),
-                               int(rng.integers(4, 11))))
-    return out
-
-
-def heavy_tail(seed: int, n: int = 32, rate: float = 16.0, p_long: float = 0.2,
-               short_max: int = 6, long_max: int = 48, vocab: int = 250) -> List[Arrival]:
-    """Poisson arrivals, lognormal prompt widths, bimodal output budgets:
-    most requests finish in a handful of tokens while a heavy tail runs an
-    order of magnitude longer — the gang scheduler's worst case."""
-    rng = np.random.default_rng(seed)
-    times = _poisson_times(rng, n, rate)
-    out = []
-    for t in times:
-        n_prompt = int(np.clip(rng.lognormal(np.log(8.0), 0.6), 2, 64))
-        if rng.random() < p_long:
-            budget = int(rng.integers(max(2, long_max // 2), long_max + 1))
-        else:
-            budget = int(rng.integers(2, short_max + 1))
-        out.append(Arrival(float(t), _prompt(rng, n_prompt, vocab), budget))
-    return out
 
 
 def drifting(seed: int, n: int = 32, shift: float = 0.5, rate: float = 16.0,
@@ -122,12 +70,7 @@ def drifting(seed: int, n: int = 32, shift: float = 0.5, rate: float = 16.0,
     return out
 
 
-SCENARIOS: Dict[str, Callable[..., List[Arrival]]] = {
-    "diurnal": diurnal,
-    "bursts": bursts,
-    "heavy_tail": heavy_tail,
-    "drifting": drifting,
-}
+SCENARIOS: Dict[str, Callable[..., List[Arrival]]] = {"drifting": drifting}
 
 
 def replay(server, arrivals: List[Arrival], speed: float = 0.0) -> Dict[str, float]:
@@ -135,8 +78,7 @@ def replay(server, arrivals: List[Arrival], speed: float = 0.0) -> Dict[str, flo
 
     ``speed`` scales scenario time onto the wall clock (2.0 = twice as fast
     as scheduled); ``speed <= 0`` disables pacing — every request is offered
-    up front (a closed burst), which is the deterministic mode benchmarks
-    use for scheduler A/B runs.  Requests are stamped with their SCHEDULED
+    up front (a closed burst), which makes the run's timing deterministic.  Requests are stamped with their SCHEDULED
     arrival time, so queueing delay from a backed-up server counts against
     latency even though `submit` happens late.
     """
@@ -161,8 +103,5 @@ def replay(server, arrivals: List[Arrival], speed: float = 0.0) -> Dict[str, flo
                 wait = (order[i].at - now) / speed
                 time.sleep(min(max(wait, 0.0), 0.05))
             continue
-        if server.mode == "continuous":
-            server.step()
-        else:
-            server.drain()   # gang blocks here; later arrivals queue up
+        server.step()
     return server.finish_run()

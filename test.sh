@@ -23,7 +23,6 @@
 #                                        benchmarks/optimizer_throughput.py --quick,
 #                                        benchmarks/configstore_roundtrip.py --quick,
 #                                        benchmarks/compile_cold_warm.py --quick,
-#                                        benchmarks/serve_scenarios.py --quick,
 #                                        benchmarks/online_tuning.py --quick
 #                                        and benchmarks/fault_tolerance.py --quick
 #                                        and asserts each wrote valid JSON
@@ -61,10 +60,6 @@ if [[ "${1:-}" == "--bench-smoke" ]]; then
   # and the xla_runtime winner must promote + resolve through the store.
   python benchmarks/compile_cold_warm.py --quick
   python -m benchmarks.check_bench compile_cold_warm --expect-quick
-  # Continuous-vs-gang serving A/B over seeded traffic mixes: the heavy-tail
-  # scenario must yield a stats.compare verdict of `improved` on tokens/s.
-  python -m benchmarks.serve_scenarios --quick
-  python -m benchmarks.check_bench serve_scenarios --expect-quick
   # Online shadow/canary tuning recovers a traffic-mix shift: at least one
   # canary promotes through the store gate, and the online-tuned server must
   # beat the frozen config on the post-shift mix (stats.compare `improved`).

@@ -25,7 +25,6 @@ class FakeServer:
     """Deterministic continuous-batching stand-in: one step = one window,
     whose tokens/s is a planted function of the live config."""
 
-    mode = "continuous"
     workload = "fake-wl"
 
     def __init__(self, perf, seed: int = 0, jitter: float = 0.01):
@@ -249,13 +248,6 @@ def test_future_schema_and_torn_rows_are_skipped(tmp_path, store):
 
 
 # -------------------------------------------------------------- guard rails
-def test_gang_server_is_rejected(tmp_path, store):
-    srv = FakeServer(_perf_flat())
-    srv.mode = "gang"
-    with pytest.raises(ValueError, match="continuous"):
-        _tuner(tmp_path, store, srv)
-
-
 def test_non_hot_swappable_space_is_rejected(tmp_path, store):
     from repro.core.registry import get_component
     space = get_component("serve_batching").space.subset(["max_batch"])

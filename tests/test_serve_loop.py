@@ -3,16 +3,18 @@
 The load-bearing property: the continuous scheduler is a pure reordering of
 work — every request's greedy token stream is bit-identical to decoding it
 alone, regardless of what shares the batch, which slot it lands in, when it
-was admitted, or how host syncs are batched.  The gang scheduler at
-``max_batch=1`` IS the sequential reference, so scheduler-vs-reference
-comparisons also pin the two engines to each other.
+was admitted, or how host syncs are batched.  The reference is a plain
+loop over ``M.prefill`` and ``M.decode_step`` (batch 1, one position) that
+re-derives the engine's width and budget rules, so it shares no scheduler
+code with the engine it checks.
 
-No raw timing assertions (conftest deflake policy): throughput claims live
-in benchmarks/serve_scenarios.py behind ``stats.compare``; here we assert
-counts, identities and state-machine invariants only.
+No raw timing assertions (conftest deflake policy): here we assert counts,
+identities and state-machine invariants only.
 """
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -28,12 +30,21 @@ from repro.runtime.serve_loop import BatchedServer
 CAPACITY = 32
 
 
+def _model(name):
+    cfg = get_config(name).reduced().validate()
+    return M.init_params(jax.random.PRNGKey(0), cfg), cfg
+
+
 @pytest.fixture(scope="module")
 def served():
-    import jax
-    cfg = get_config("olmo-1b").reduced().validate()
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    return params, cfg
+    return _model("olmo-1b")
+
+
+# olmo-1b reduced: MHA, sequence-major cache.  StarCoder2-15B reduced: GQA
+# 4/2 (head-major cache), biases, a 16-slot ring under CAPACITY 32.
+@pytest.fixture(scope="module", params=["olmo-1b", "starcoder2-15b"])
+def any_served(request):
+    return _model(request.param)
 
 
 def _prompts(n, seed=0, lo=3, hi=14):
@@ -42,10 +53,10 @@ def _prompts(n, seed=0, lo=3, hi=14):
             for k in rng.integers(lo, hi, size=n)]
 
 
-def _serve(served, mode, settings, prompts, budget, eos_id=-1):
+def _serve(served, settings, prompts, budget, eos_id=-1):
     params, cfg = served
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=eos_id,
-                      mode=mode, settings=settings)
+                      settings=settings)
     for p in prompts:
         s.submit(p)
     metrics = s.run(max_new_tokens=budget)
@@ -54,6 +65,33 @@ def _serve(served, mode, settings, prompts, budget, eos_id=-1):
 
 def _token_streams(server):
     return {r.rid: list(r.tokens) for r in server.results.values()}
+
+
+def _sequential(served, prompts, budget, eos_id=-1):
+    """Each prompt decoded alone: keep the last CAPACITY // 2 tokens,
+    left-pad to the next power of two (at least 2), prefill, then greedy
+    decode one position at a time from ``pos = width`` until ``eos_id`` or
+    the budget (clipped to CAPACITY - width for a cache that must not wrap)."""
+    params, cfg = served
+    prefill = jax.jit(lambda p, t: M.prefill(p, cfg, t, CAPACITY)[:2])
+    decode = jax.jit(lambda p, t, c, pos: M.decode_step(p, cfg, t, c, pos))
+    streams = {}
+    for rid, prompt in enumerate(prompts):
+        keep = prompt[-(CAPACITY // 2):]
+        width = max(2, 1 << (len(keep) - 1).bit_length())
+        toks = np.zeros((1, width), np.int32)
+        toks[0, width - len(keep):] = keep
+        b = budget if cfg.window else min(budget, CAPACITY - width)
+        logits, caches = prefill(params, jnp.asarray(toks))
+        out = [int(jnp.argmax(logits[0]))]
+        pos = width
+        while len(out) < b and out[-1] != eos_id:
+            logits, caches = decode(params, jnp.asarray([out[-1]], jnp.int32),
+                                    caches, jnp.asarray(pos, jnp.int32))
+            out.append(int(jnp.argmax(logits[0])))
+            pos += 1
+        streams[rid] = out
+    return streams
 
 
 # -------------------------------------------------------- KV read counter
@@ -67,7 +105,7 @@ def test_kv_read_share_counts_the_blocks_decode_reads():
     from repro.kernels.flash_attention.kernel import decode_block
 
     cfg = get_config("olmo-1b")
-    srv = BatchedServer(None, cfg, capacity=2048, mode="continuous",
+    srv = BatchedServer(None, cfg, capacity=2048,
                         settings={"max_batch": 8, "sync_interval": 4})
     assert decode_block(2048, cfg.n_kv_heads, cfg.hd, False) == 256
     for slot, width, emitted in ((0, 512, 0), (3, 1024, 250), (5, 128, 127)):
@@ -82,7 +120,7 @@ def test_kv_read_share_reads_all_of_a_wrapped_ring():
     a slot past the ring's end reads all 4 a decode, one at width 2048 with
     2 emitted reads 2051-2052 tokens, 3 blocks; 32 slots, 2 decodes a sync."""
     cfg = get_config("starcoder2-15b")
-    srv = BatchedServer(None, cfg, capacity=4096, mode="continuous",
+    srv = BatchedServer(None, cfg, capacity=4096,
                         settings={"max_batch": 32, "sync_interval": 2})
     assert cfg.cache_len(4096) == cfg.window == 4096
     for slot, width, emitted in ((1, 2048, 2100), (2, 2048, 2)):
@@ -93,14 +131,13 @@ def test_kv_read_share_reads_all_of_a_wrapped_ring():
 
 
 # ------------------------------------------------------- scheduler identity
-def test_mixed_prompt_lengths_match_sequential_reference(served):
-    """Mixed widths across slots: continuous output == one-at-a-time gang."""
+def test_mixed_prompt_lengths_match_sequential_reference(any_served):
+    """Mixed widths across slots: engine output == each prompt decoded alone."""
     prompts = _prompts(5, seed=1)
-    ref, _ = _serve(served, "gang", {"max_batch": 1}, prompts, budget=6)
-    srv, m = _serve(served, "continuous",
+    srv, m = _serve(any_served,
                     {"max_batch": 3, "admission": 2, "prefill_chunk": 16,
                      "sync_interval": 2}, prompts, budget=6)
-    assert _token_streams(srv) == _token_streams(ref)
+    assert _token_streams(srv) == _sequential(any_served, prompts, budget=6)
     assert m["completed"] == 5 and m["queue_depth"] == 0 and m["live_slots"] == 0
 
 
@@ -110,14 +147,11 @@ def test_eos_frees_slot_midflight_and_queued_request_is_admitted(served):
     prompts = _prompts(4, seed=2)
     # discover a token that actually occurs early in request 0's stream and
     # use it as the EOS id — forcing a genuine mid-flight completion
-    ref_free, _ = _serve(served, "gang", {"max_batch": 1}, prompts, budget=8)
-    eos = _token_streams(ref_free)[0][2]
-    ref, _ = _serve(served, "gang", {"max_batch": 1}, prompts, budget=8,
-                    eos_id=eos)
-    srv, m = _serve(served, "continuous",
-                    {"max_batch": 2, "admission": 1, "sync_interval": 1},
+    eos = _sequential(served, prompts, budget=8)[0][2]
+    srv, m = _serve(served, {"max_batch": 2, "admission": 1, "sync_interval": 1},
                     prompts, budget=8, eos_id=eos)
-    assert _token_streams(srv) == _token_streams(ref)
+    assert _token_streams(srv) == _sequential(served, prompts, budget=8,
+                                              eos_id=eos)
     assert m["completed"] == 4
     eos_req = srv.results[0]
     assert eos_req.tokens[-1] == eos and len(eos_req.tokens) < 8
@@ -140,12 +174,10 @@ def test_sync_interval_amortizes_host_syncs_bitidentically(served, monkeypatch):
 
     monkeypatch.setattr(serve_loop, "_host_fetch", counted)
     base = {"max_batch": 3, "admission": 3, "prefill_chunk": 64}
-    srv1, m1 = _serve(served, "continuous", dict(base, sync_interval=1),
-                      prompts, budget=7)
+    srv1, m1 = _serve(served, dict(base, sync_interval=1), prompts, budget=7)
     n1 = calls["n"]
     calls["n"] = 0
-    srv5, m5 = _serve(served, "continuous", dict(base, sync_interval=5),
-                      prompts, budget=7)
+    srv5, m5 = _serve(served, dict(base, sync_interval=5), prompts, budget=7)
     n5 = calls["n"]
     assert _token_streams(srv5) == _token_streams(srv1)
     # one _host_fetch per interval, none anywhere else in the loop
@@ -157,37 +189,45 @@ def test_sync_interval_amortizes_host_syncs_bitidentically(served, monkeypatch):
 # ------------------------------------------------------------- edge cases
 def test_empty_queue_run_is_a_noop(served):
     params, cfg = served
-    s = BatchedServer(params, cfg, capacity=CAPACITY, mode="continuous")
+    s = BatchedServer(params, cfg, capacity=CAPACITY)
     m = s.run()
     assert m["completed"] == 0 and m["total_tokens"] == 0
     assert m["decode_steps"] == 0 and m["decode_syncs"] == 0
 
 
+def test_only_the_continuous_scheduler_is_accepted(served):
+    """``mode`` keeps one legal value: callers passing it still construct
+    the same server, and any other scheduler name is refused."""
+    params, cfg = served
+    named = BatchedServer(params, cfg, capacity=CAPACITY, mode="continuous")
+    plain = BatchedServer(params, cfg, capacity=CAPACITY)
+    assert named.current_config() == plain.current_config()
+    with pytest.raises(ValueError, match="gang"):
+        BatchedServer(params, cfg, capacity=CAPACITY, mode="gang")
+
+
 def test_single_request_serves_alone(served):
     prompts = _prompts(1, seed=4)
-    ref, _ = _serve(served, "gang", {"max_batch": 1}, prompts, budget=5)
-    srv, m = _serve(served, "continuous", {"max_batch": 4, "sync_interval": 3},
-                    prompts, budget=5)
-    assert _token_streams(srv) == _token_streams(ref)
+    srv, m = _serve(served, {"max_batch": 4, "sync_interval": 3}, prompts,
+                    budget=5)
+    assert _token_streams(srv) == _sequential(served, prompts, budget=5)
     assert m["completed"] == 1 and m["total_tokens"] == 5
 
 
 def test_budget_clipped_so_full_cache_never_wraps(served):
     """Non-windowed cache: width + budget must stay <= capacity."""
     prompts = [np.arange(2, 2 + 14, dtype=np.int32)]   # width buckets to 16
-    srv, m = _serve(served, "continuous", {"max_batch": 2}, prompts,
-                    budget=10_000)
+    srv, m = _serve(served, {"max_batch": 2}, prompts, budget=10_000)
     r = srv.results[0]
     assert len(r.tokens) == CAPACITY - 16   # eff budget = capacity - width
 
 
 # ------------------------------------------------- per-run metric isolation
-@pytest.mark.parametrize("mode", ["gang", "continuous"])
-def test_run_metrics_cover_this_run_only(served, mode):
+def test_run_metrics_cover_this_run_only(served):
     """The seed's self.results pollution bug: metrics must cover this run's
     completions, not every request the server ever served."""
     params, cfg = served
-    s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1, mode=mode,
+    s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
                       settings={"max_batch": 2})
     for p in _prompts(3, seed=5):
         s.submit(p)
@@ -207,7 +247,7 @@ def test_prefill_widths_are_pow2_bucketed(served):
     params, cfg = served
     widths = []
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                      mode="continuous", settings={"max_batch": 2})
+                      settings={"max_batch": 2})
     real = s._prefill_fn
 
     def spy(p, toks, modal):
@@ -232,8 +272,7 @@ def test_serve_telemetry_reaches_the_agent_channel(served):
     try:
         emitter = TelemetryEmitter(meta, chan)
         s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                          mode="continuous", settings={"max_batch": 2},
-                          emitter=emitter)
+                          settings={"max_batch": 2}, emitter=emitter)
         for p in _prompts(3, seed=7):
             s.submit(p)
         m = s.run(max_new_tokens=4)
@@ -261,33 +300,31 @@ def test_traffic_generators_are_seeded_and_sorted():
         assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
         assert [x.at for x in a] == sorted(x.at for x in a), name
         assert all(x.budget >= 1 and len(x.prompt) >= 2 for x in a), name
-    assert not np.array_equal(traffic.heavy_tail(1, n=4)[0].prompt,
-                              traffic.heavy_tail(2, n=4)[0].prompt)
+    assert not np.array_equal(traffic.drifting(1, n=4)[0].prompt,
+                              traffic.drifting(2, n=4)[0].prompt)
 
 
 def test_open_loop_replay_backdates_queueing_delay(served):
     """Paced replay stamps requests with their SCHEDULED arrival, so server
     backlog shows up as latency; the drain path serves everything."""
     params, cfg = served
-    arr = traffic.heavy_tail(13, n=6, long_max=8)
+    arr = traffic.drifting(13, n=6, long_budget=8)
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                      mode="continuous", settings={"max_batch": 2})
+                      settings={"max_batch": 2})
     m = traffic.replay(s, arr, speed=50.0)
     assert m["completed"] == 6
     assert all(r.finished_at > r.submitted for r in s.results.values())
 
 
 # ------------------------------------------------------------- hot swapping
-def test_hot_swap_mid_run_preserves_bit_identity(served):
+def test_hot_swap_mid_run_preserves_bit_identity(any_served):
     """Online tuning's load-bearing precondition: re-knobbing the scheduler
     at sync boundaries (the only points a controller can interpose) is still
     a pure reordering — every token stream stays bit-identical to the
-    sequential gang reference no matter how the knobs thrash mid-run."""
+    sequential reference no matter how the knobs thrash mid-run."""
     prompts = _prompts(6, seed=9)
-    ref, _ = _serve(served, "gang", {"max_batch": 1}, prompts, budget=8)
-    params, cfg = served
+    params, cfg = any_served
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                      mode="continuous",
                       settings={"max_batch": 3, "admission": 2,
                                 "prefill_chunk": 16, "sync_interval": 2})
     for p in prompts:
@@ -302,14 +339,14 @@ def test_hot_swap_mid_run_preserves_bit_identity(served):
         s.step()
     m = s.finish_run()
     assert i >= 3, "run too short to exercise every swap"
-    assert _token_streams(s) == _token_streams(ref)
+    assert _token_streams(s) == _sequential(any_served, prompts, budget=8)
     assert m["completed"] == 6
 
 
 def test_apply_config_rejects_shape_baked_knobs(served):
     params, cfg = served
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                      mode="continuous", settings={"max_batch": 2})
+                      settings={"max_batch": 2})
     with pytest.raises(ValueError, match="max_batch"):
         s.apply_config({"max_batch": 4})
     with pytest.raises(ValueError, match="bogus"):
@@ -327,7 +364,6 @@ def test_rolling_telemetry_is_windowed_and_resets_between_runs(served):
     window state (no leakage from the previous run's totals)."""
     params, cfg = served
     s = BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
-                      mode="continuous",
                       settings={"max_batch": 2, "admission": 1,
                                 "prefill_chunk": 8, "sync_interval": 2})
     for p in _prompts(4, seed=11):

@@ -175,7 +175,7 @@ def serve_compiled(one_chip):
                 cfg = dataclasses.replace(cfg, n_layers=layers)
             params = jax.eval_shape(lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0))
             params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), params)
-            srv = BatchedServer(None, cfg, capacity=capacity, mode="continuous",
+            srv = BatchedServer(None, cfg, capacity=capacity,
                                 settings={"max_batch": max_batch})
             caches = jax.eval_shape(lambda: M.init_cache(cfg, max_batch, capacity, srv._enc_len))
             caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), caches)

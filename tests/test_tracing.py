@@ -29,7 +29,7 @@ def served():
 
 def _server(served, sync_interval=3):
     params, cfg = served
-    return BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1, mode="continuous",
+    return BatchedServer(params, cfg, capacity=CAPACITY, eos_id=-1,
                          settings={"max_batch": 2, "sync_interval": sync_interval})
 
 
@@ -103,8 +103,7 @@ def test_decode_span_counts_the_kv_blocks_read(tmp_path):
     cfg = dataclasses.replace(get_config("olmo-1b").reduced(), n_heads=8, n_kv_heads=8,
                               head_dim=128).validate()
     srv = BatchedServer(M.init_params(jax.random.PRNGKey(0), cfg), cfg, capacity=CAPACITY,
-                        eos_id=-1, mode="continuous",
-                        settings={"max_batch": 4, "sync_interval": 3})
+                        eos_id=-1, settings={"max_batch": 4, "sync_interval": 3})
     srv.submit(_prompts(1, seed=1)[0])
     srv.step()                                 # compiles outside the trace
     srv.submit(_prompts(1, seed=2)[0])
